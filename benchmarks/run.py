@@ -773,7 +773,10 @@ DEVICE_SCALING_STATS: dict = {}
 # worker for bench_device_scaling: runs in a subprocess because
 # XLA_FLAGS=--xla_force_host_platform_device_count must be set before jax
 # is first imported, and the parent process has usually imported it
-# already.  Prints one JSON document on the last stdout line.
+# already.  CPU hosts only: on an accelerator the parent holds the chip, so
+# a child cannot take it (and the forced host-device flag does not apply);
+# `python chip_smoke.py --chips 4` drives the mesh on four real chips.
+# Prints one JSON document on the last stdout line.
 _DEVICE_SCALING_WORKER = """
 import json, os, random, time
 from repro.core.batch_sim import BatchSimMachine
@@ -836,17 +839,25 @@ def bench_device_scaling(smoke: bool = False):
     share the machine's physical cores — wall-clock scaling tracks the
     spare core count (``cpu_count`` is recorded alongside), and on a
     single-core host the 4-device row measures sharding overhead, not
-    speedup; real accelerators (or real cores) are where the mesh pays."""
+    speedup; real accelerators (or real cores) are where the mesh pays.
+    CPU-only: on an accelerator it raises (see ``chip_smoke.py --chips
+    4`` for the four-chip mesh)."""
     import json as _json
     import os
     import subprocess
 
     try:
-        import jax  # noqa: F401
+        import jax
     except ImportError:
         print("\n== device scaling: jax unavailable, skipped ==")
         DEVICE_SCALING_STATS.update({"skipped": "jax not importable"})
         return
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"bench_device_scaling forces host devices in a child process, "
+            f"which cannot take the {jax.default_backend()} chip this "
+            f"process holds; run `python chip_smoke.py --chips 4` for the "
+            f"multi-chip mesh instead")
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:

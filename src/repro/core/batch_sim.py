@@ -19,8 +19,14 @@ a vectorized kernel.  Three backends share the lowering and packing layers:
 * ``pallas`` — the same recurrence as a ``pl.pallas_call`` kernel: the grid
   runs over blocks of experiment lanes, a ``fori_loop`` walks μop positions
   with the per-lane state (``done`` history, port-free times, port counts)
-  carried in on-chip values.  Off-TPU it executes in interpret mode — the
-  correctness twin of the compiled TPU kernel, not a speed path.
+  carried in on-chip values.  It runs in interpret mode off-TPU only — the
+  correctness twin of the recurrence, not a speed path: Mosaic refuses its
+  per-step gathers (``dynamic_slice``) for the TPU, so on a TPU the
+  backend is refused at construction.
+
+With no backend given, :class:`~repro.core.simulator.SimMachine` picks the
+kernel from the platform (:func:`default_backend`): ``jax`` on a TPU,
+``numpy`` elsewhere.
 
 Wave execution is amortized end-to-end:
 
@@ -96,6 +102,24 @@ DEFAULT_LOWER_CACHE = 4096
 
 # lane-block width for the pallas kernel grid (the TPU lane dimension)
 _PALLAS_LANE_BLOCK = 128
+
+# why the pallas backend is refused on a TPU (pinned by
+# tests/test_tpu_compile.py, which compiles it for a described chip)
+PALLAS_TPU_REFUSAL = ("the pallas wave kernel does not compile for the TPU: "
+                      "Mosaic has no lowering for its per-step gathers "
+                      "(Unimplemented primitive in Pallas TPU lowering: "
+                      "dynamic_slice); use backend='jax'")
+
+
+class BackendUnavailable(RuntimeError):
+    """A device backend was requested but jax is not importable."""
+
+
+def default_backend() -> str:
+    """The wave backend the platform picks: the ``jax`` scan kernel on a
+    TPU, the ``numpy`` kernel everywhere else."""
+    from repro.core.device_mesh import device_platform  # noqa: PLC0415
+    return "jax" if device_platform() == "tpu" else "numpy"
 
 
 def _fault_key(code) -> str:
@@ -253,8 +277,13 @@ class BatchSimMachine:
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}")
         if backend != "numpy" and _jax() is None:
-            raise RuntimeError(f"{backend} backend requested but jax is "
-                               "not importable")
+            raise BackendUnavailable(f"{backend} backend requested but jax "
+                                     "is not importable")
+        if backend == "pallas":
+            from repro.core.device_mesh import (  # noqa: PLC0415
+                device_platform)
+            if device_platform() == "tpu":
+                raise NotImplementedError(PALLAS_TPU_REFUSAL)
         self.uarch = uarch
         self.isa = isa
         self.name = uarch.name
@@ -1521,7 +1550,6 @@ def _compiled_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
     takes ``(E * mesh.n, S)``-shaped operands whose lane blocks land one
     per device.  Executables are device-bound, so the mesh's device-id
     tuple is part of the cache key."""
-    jax = _jax()
     key = (kind, S, E, R, M, P) + ((mesh.key,) if mesh is not None else ())
     hit = _EXEC_CACHE.get(key)
     if hit is not None:
@@ -1530,48 +1558,64 @@ def _compiled_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
         hit = _EXEC_CACHE.get(key)      # double-check under the lock
         if hit is not None:
             return hit, False
+        from repro.core.device_mesh import (  # noqa: PLC0415
+            enable_compile_cache)
+        enable_compile_cache()
         with obs.span("wave.compile", backend=kind, bucket=list(key[1:6])):
-            return _compile_kernel(jax, kind, key, mesh), True
+            return _compile_kernel(kind, key, mesh), True
 
 
-def _compile_kernel(jax, kind, key, mesh=None):
-    S, E, R, M, P = key[1:6]
+def lower_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
+                 mesh=None, sharding=None):
+    """Lower the dispatch kernel for one shape bucket (``jax.stages.
+    Lowered``; ``.compile()`` it).  Without ``mesh`` the operands are
+    placed by ``sharding`` (default: jax's default device) — pass a
+    sharding on a described TPU device to compile for a chip that is not
+    attached.  With ``mesh`` the kernel is wrapped in ``shard_map`` over
+    the mesh's ``lanes`` axis and ``E`` is the per-device lane width."""
+    jax = _jax()
     import jax.numpy as jnp
 
-    fn = (_build_pallas_fn(S, E, R, M, P) if kind == "pallas"
-          else _build_scan_fn())
-    if mesh is None:
-        shapes = (jax.ShapeDtypeStruct((E, S), jnp.int32),
-                  jax.ShapeDtypeStruct((E, S), jnp.int32),
-                  jax.ShapeDtypeStruct((E, S), jnp.int32),
-                  jax.ShapeDtypeStruct((E, S), jnp.int32),
-                  jax.ShapeDtypeStruct((E, S), jnp.bool_),
-                  jax.ShapeDtypeStruct((E, S, R), jnp.int32),
-                  jax.ShapeDtypeStruct((E, S, R), jnp.int32),
-                  jax.ShapeDtypeStruct((M, P), jnp.bool_))
+    if mesh is not None:
+        device = mesh.devices[0]
+    elif sharding is not None:
+        device = next(iter(sharding.device_set))
     else:
-        from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+        device = jax.devices()[0]
+    fn = (_build_pallas_fn(S, E, R, M, P,
+                           interpret=device.platform != "tpu")
+          if kind == "pallas" else _build_scan_fn())
+    sd = jax.ShapeDtypeStruct
+    if mesh is None:
+        s2 = s3 = rep = sharding
+        Eg = E
+    else:
         # the per-shard fn sees (E, S) blocks; lanes are independent, so
         # no collectives and no replication to check
-        fn = shard_map(
+        fn = jax.shard_map(
             fn, mesh=mesh.mesh,
             in_specs=(mesh.spec2,) * 5 + (mesh.spec3,) * 2
             + (mesh.repl_spec,),
-            out_specs=(mesh.spec2, mesh.spec2), check_rep=False)
+            out_specs=(mesh.spec2, mesh.spec2), check_vma=False)
+        s2, s3, rep = mesh.shard2, mesh.shard3, mesh.replicated
         Eg = E * mesh.n
-        sd = jax.ShapeDtypeStruct
-        shapes = (sd((Eg, S), jnp.int32, sharding=mesh.shard2),
-                  sd((Eg, S), jnp.int32, sharding=mesh.shard2),
-                  sd((Eg, S), jnp.int32, sharding=mesh.shard2),
-                  sd((Eg, S), jnp.int32, sharding=mesh.shard2),
-                  sd((Eg, S), jnp.bool_, sharding=mesh.shard2),
-                  sd((Eg, S, R), jnp.int32, sharding=mesh.shard3),
-                  sd((Eg, S, R), jnp.int32, sharding=mesh.shard3),
-                  sd((M, P), jnp.bool_, sharding=mesh.replicated))
-    # donation lets XLA alias the bucket input buffers for outputs; it is
-    # unimplemented on the CPU backend (emits warnings), so gate on device
-    donate = tuple(range(7)) if jax.default_backend() != "cpu" else ()
-    compiled = jax.jit(fn, donate_argnums=donate).lower(*shapes).compile()
+    shapes = (sd((Eg, S), jnp.int32, sharding=s2),
+              sd((Eg, S), jnp.int32, sharding=s2),
+              sd((Eg, S), jnp.int32, sharding=s2),
+              sd((Eg, S), jnp.int32, sharding=s2),
+              sd((Eg, S), jnp.bool_, sharding=s2),
+              sd((Eg, S, R), jnp.int32, sharding=s3),
+              sd((Eg, S, R), jnp.int32, sharding=s3),
+              sd((M, P), jnp.bool_, sharding=rep))
+    # donating the issue plane lets XLA write the (E, S) ``done`` output
+    # into it (the only input an output can alias); the CPU backend does
+    # not implement donation, so gate on the target device's platform
+    donate = (0,) if device.platform != "cpu" else ()
+    return jax.jit(fn, donate_argnums=donate).lower(*shapes)
+
+
+def _compile_kernel(kind, key, mesh=None):
+    compiled = lower_kernel(kind, *key[1:6], mesh=mesh).compile()
     while len(_EXEC_CACHE) >= _EXEC_CACHE_MAX:
         _EXEC_CACHE.pop(next(iter(_EXEC_CACHE)))
     _EXEC_CACHE[key] = compiled
@@ -1694,13 +1738,14 @@ def _build_scan_fn():
     return run
 
 
-def _build_pallas_fn(S: int, E: int, R: int, M: int, P: int):
+def _build_pallas_fn(S: int, E: int, R: int, M: int, P: int,
+                     interpret: bool):
     """The dispatch recurrence as a ``pl.pallas_call`` kernel: grid over
     blocks of experiment lanes, ``fori_loop`` over μop positions, per-lane
     state (``done`` history, port-free times, port counts) carried in
-    on-chip values.  Off-TPU it runs in interpret mode (the lax.scan
-    kernel above is the performance fallback there); the tie-break is the
-    same two-pass min as the scan kernel."""
+    on-chip values; the tie-break is the same two-pass min as the scan
+    kernel.  Off-TPU it runs in ``interpret`` mode; compiled for a TPU,
+    Mosaic refuses it (see :data:`PALLAS_TPU_REFUSAL`)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1769,7 +1814,7 @@ def _build_pallas_fn(S: int, E: int, R: int, M: int, P: int):
                    pl.BlockSpec((B, P), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((E, S), jnp.int32),
                    jax.ShapeDtypeStruct((E, P), jnp.int32)],
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )
 
     def run(issue, mask_id, lat, blk, valid, prod, delta, lut):

@@ -13,13 +13,20 @@ This module owns the pieces of that substrate that are *not* kernel code:
   spec (``devices=`` constructor argument or the ``REPRO_SIM_DEVICES``
   environment variable: an integer count, ``"all"``, or an explicit
   device sequence) into an ordered tuple of jax devices, clamped to what
-  the host actually has.  Real accelerators appear here on real hardware;
-  CPU CI forces host devices via
+  the host actually has.  Real accelerators appear here on real hardware
+  (``python chip_smoke.py --chips 4`` drives the mesh on a four-chip TPU
+  host); CPU CI forces host devices via
   ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (which must be
   set *before* jax is first imported — hence the subprocess pattern in
-  ``tests/test_multidevice.py`` and ``bench_device_scaling``).  With jax
-  missing or a single device the resolution degrades gracefully and wave
-  execution stays on the PR-5 single-device path, bit-identical.
+  ``tests/test_multidevice.py`` and ``bench_device_scaling``, which is
+  CPU-only: a child process cannot take a chip its parent holds).  With
+  jax missing or a single device the resolution degrades gracefully and
+  wave execution stays on the single-device path, bit-identical.
+
+* **Platform and compile cache** — :func:`device_platform` is what the
+  wave backend and the compile cache are chosen from (never a user
+  knob); :func:`enable_compile_cache` turns JAX's persistent compilation
+  cache on before the first kernel compile on a TPU.
 
 * **Mesh construction** — :class:`LaneMesh` wraps a
   ``jax.sharding.Mesh`` over an ordered device subset with the
@@ -48,11 +55,52 @@ from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
 
 from repro.obs import tracer as obs
 
 # user-facing device-count knob (int or "all"); unset means "all available"
 ENV_DEVICES = "REPRO_SIM_DEVICES"
+
+# JAX reads this itself; when it is set the program configures no cache
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
+# the compile cache's fixed home inside the checkout (the directory is part
+# of every cache key, so it must not move between runs)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def device_platform() -> str | None:
+    """The platform jax computes on by default (``"cpu"``, ``"tpu"``, ...),
+    or ``None`` when jax is not importable."""
+    try:
+        import jax  # noqa: PLC0415
+    except ImportError:
+        return None
+    return jax.default_backend()
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache; call before a kernel's
+    first compile.  With ``JAX_COMPILATION_CACHE_DIR`` set JAX already
+    reads it and nothing is configured here; otherwise, on a TPU only, the
+    cache goes to :data:`COMPILE_CACHE_DIR` and keeps every kernel,
+    however fast its compile (CPU runs, and compiles for a described chip,
+    write nothing).  Returns the cache directory in use, or ``None``."""
+    env = os.environ.get(ENV_COMPILE_CACHE)
+    if env:
+        return env
+    if device_platform() != "tpu":
+        return None
+    path = str(COMPILE_CACHE_DIR)
+    import jax  # noqa: PLC0415
+    if jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import (  # noqa: PLC0415
+            compilation_cache)
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        # a cache already checked in this process kept no directory
+        compilation_cache.reset_cache()
+    return path
 
 
 def jax_devices() -> tuple:

@@ -78,10 +78,11 @@ class SimMachine:
 
     ``backend`` selects the batched wave-execution kernel (``numpy``,
     ``jax``, or ``pallas``; default: the ``REPRO_SIM_BACKEND`` environment
-    variable, else ``numpy``) — results are bit-identical on every
-    backend.  ``min_lanes`` is the thin-chunk scalar-oracle crossover
-    forwarded to :class:`~repro.core.batch_sim.BatchSimMachine` (default:
-    the measured crossover, see ``bench_batch_sim``).  ``devices``
+    variable, else the platform's pick — ``jax`` on a TPU, ``numpy``
+    elsewhere) — results are bit-identical on every backend.
+    ``min_lanes`` is the thin-chunk scalar-oracle crossover forwarded to
+    :class:`~repro.core.batch_sim.BatchSimMachine` (default: the measured
+    crossover, see ``bench_batch_sim``).  ``devices``
     selects the device placement for the jax/pallas backends (an integer
     count, ``"all"``, or an explicit jax device sequence; default: the
     ``REPRO_SIM_DEVICES`` environment variable, else all available) —
@@ -156,18 +157,27 @@ class SimMachine:
         codes = list(codes)
         degenerate = 4 if self.min_lanes is None else \
             min(4, max(self.min_lanes, 1))
-        if len(codes) < degenerate:
-            if kernel_lock is not None:
-                with kernel_lock:
-                    return [self.run(list(c)) for c in codes]
-            return [self.run(list(c)) for c in codes]
+        batch = self.batch_machine() if len(codes) >= degenerate else False
+        if batch:
+            return batch.run_batch(codes, kernel_lock=kernel_lock)
+        if kernel_lock is not None:
+            with kernel_lock:
+                return [self.run(list(c)) for c in codes]
+        return [self.run(list(c)) for c in codes]
+
+    def batch_machine(self):
+        """The batched backend behind :meth:`run_batch`, built on first
+        use on the configured (else the platform's) kernel; ``False`` when
+        numpy is missing and waves run on the scalar loop."""
         if self._batch is None:
             try:
                 from repro.core.batch_sim import (  # noqa: PLC0415
-                    DEFAULT_MIN_LANES, BatchSimMachine)
+                    DEFAULT_MIN_LANES, BackendUnavailable, BatchSimMachine,
+                    default_backend)
                 import os  # noqa: PLC0415
-                backend = self.backend or os.environ.get(
-                    "REPRO_SIM_BACKEND", "numpy")
+                backend = (self.backend
+                           or os.environ.get("REPRO_SIM_BACKEND")
+                           or default_backend())
                 min_lanes = (DEFAULT_MIN_LANES if self.min_lanes is None
                              else self.min_lanes)
                 try:
@@ -175,22 +185,17 @@ class SimMachine:
                         self.uarch, self.isa, backend=backend,
                         table_index=self._table_index, min_lanes=min_lanes,
                         devices=self.devices)
-                except RuntimeError:   # jax backend requested, jax missing
+                except BackendUnavailable:   # jax backend, jax missing
                     import warnings  # noqa: PLC0415
                     warnings.warn(f"sim backend {backend!r} unavailable "
                                   "(jax not importable); falling back to "
-                                  "numpy", stacklevel=2)
+                                  "numpy", stacklevel=3)
                     self._batch = BatchSimMachine(
                         self.uarch, self.isa, backend="numpy",
                         table_index=self._table_index, min_lanes=min_lanes)
             except ImportError:   # no numpy: scalar fallback
                 self._batch = False
-        if self._batch:
-            return self._batch.run_batch(codes, kernel_lock=kernel_lock)
-        if kernel_lock is not None:
-            with kernel_lock:
-                return [self.run(list(c)) for c in codes]
-        return [self.run(list(c)) for c in codes]
+        return self._batch
 
     # ------------------------------------------------------------------
     def run(self, code: list[Instr]) -> Counters:

@@ -41,7 +41,8 @@ def main(argv=None) -> int:
     e.add_argument("--corpus", default="experiments/corpus")
     e.add_argument("--uarch", action="append")
     e.add_argument("--backend", default=None,
-                   help="wave backend (default: REPRO_SIM_BACKEND)")
+                   help="wave backend (default: REPRO_SIM_BACKEND, else "
+                        "jax on a TPU and numpy elsewhere)")
     e.add_argument("--wave-width", type=int, default=2048)
     e.add_argument("--no-resume", action="store_true",
                    help="ignore per-shard result files")

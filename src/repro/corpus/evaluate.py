@@ -2,8 +2,9 @@
 
 For each uarch in the corpus manifest this driver
 
-1. builds the simulated machine (``REPRO_SIM_BACKEND`` selects the wave
-   backend, ``devices`` the mesh placement) and characterizes exactly the
+1. builds the simulated machine (``backend``, else ``REPRO_SIM_BACKEND``,
+   else the platform picks the wave backend; ``devices`` the mesh
+   placement) and characterizes exactly the
    variants the corpus uses — through the *same* measurement engine the
    ground-truth waves run on, so characterization experiments and corpus
    blocks share one content-addressed cache;
@@ -21,8 +22,10 @@ For each uarch in the corpus manifest this driver
 The returned results dict feeds :func:`repro.corpus.score.score_results`;
 ``wave_stats``/``engine_stats`` carry the fused-wave telemetry
 (``max_wave_width`` is the acceptance probe that mega-waves actually
-formed). Observability: ``corpus.evaluate`` → ``corpus.uarch`` →
-``corpus.wave`` spans thread through generation → simulate → score when
+formed), and ``backends`` each evaluated uarch's wave-kernel and
+predictor-kernel counters (which kernels ran, and any degradation).
+Observability: ``corpus.evaluate`` → ``corpus.uarch`` → ``corpus.wave``
+spans thread through generation → simulate → score when
 ``REPRO_TRACE=1``.
 """
 from __future__ import annotations
@@ -129,6 +132,7 @@ def evaluate_corpus(corpus_dir, *, uarches=None, isa=None,
     by_uarch: dict[str, list[dict]] = {}
     waves = _WaveStats()
     agg_engine: dict[str, int] = {}
+    backends: dict[str, dict] = {}
     with obs.span("corpus.evaluate", corpus=manifest["corpus_id"][:12],
                   shards=len(manifest["shards"])):
         for ua in sorted({s["uarch"] for s in manifest["shards"]}):
@@ -138,15 +142,15 @@ def evaluate_corpus(corpus_dir, *, uarches=None, isa=None,
             by_uarch[ua] = _evaluate_uarch(
                 corpus_dir, ua, shards, isa, backend, devices, wave_width,
                 results_dir, resume, (models or {}).get(ua), predict_fn,
-                kernel_lock, waves, agg_engine)
+                kernel_lock, waves, agg_engine, backends)
     return {"corpus_id": manifest["corpus_id"], "spec": manifest["spec"],
             "uarches": by_uarch, "wave_stats": waves.as_dict(),
-            "engine_stats": agg_engine}
+            "engine_stats": agg_engine, "backends": backends}
 
 
 def _evaluate_uarch(corpus_dir, ua, shards, isa, backend, devices,
                     wave_width, results_dir, resume, model, predict_fn,
-                    kernel_lock, waves, agg_engine) -> list[dict]:
+                    kernel_lock, waves, agg_engine, backends) -> list[dict]:
     from repro.service.protocol import parse_block  # noqa: PLC0415
 
     rows_by_shard: dict[str, list] = {}
@@ -178,6 +182,9 @@ def _evaluate_uarch(corpus_dir, ua, shards, isa, backend, devices,
             for k, v0 in stats0.items():
                 d = engine.stats.as_dict()[k] - v0
                 agg_engine[k] = agg_engine.get(k, 0) + d
+            backends[ua] = {"device": machine.device_stats(),
+                            "degraded": machine.degraded_stats(),
+                            "predictor": bp.backend_stats()}
     # submission order == manifest order, resumed or not
     return [row for shard in shards for row in rows_by_shard[shard["name"]]]
 

@@ -278,7 +278,7 @@ class BatchPredictor:
         if u.shape[0] != bucket:
             u = np.concatenate(
                 [u, np.zeros((bucket - u.shape[0], u.shape[1]), u.dtype)])
-        num, den = fn(u)
+        num, den = fn(u, self._dev_mask, self._dev_size)
         return np.asarray(num), np.asarray(den)
 
     def _compile_kernel(self, bucket: int):
@@ -287,6 +287,8 @@ class BatchPredictor:
         and sizes live on the device across calls."""
         import jax  # noqa: PLC0415
         import jax.numpy as jnp  # noqa: PLC0415
+        from repro.core.device_mesh import (  # noqa: PLC0415
+            enable_compile_cache)
 
         with self._dev_lock:
             fn = self._dev_kernels.get(bucket)
@@ -297,27 +299,9 @@ class BatchPredictor:
                     jnp.asarray(self._mask_i, dtype=jnp.int32))
                 self._dev_size = jax.device_put(
                     jnp.asarray(self._size_i, dtype=jnp.int32))
-            mask_d, size_d = self._dev_mask, self._dev_size
-
-            def port_bound_kernel(u):
-                demand = u @ mask_d                     # B×S int32, exact
-                den = jnp.broadcast_to(size_d, demand.shape)
-
-                def pick(acc, x):
-                    an, ad = acc
-                    bn, bd = x
-                    take = an * bd < bn * ad            # exact ratio compare
-                    return (jnp.where(take, bn, an), jnp.where(take, bd, ad))
-
-                num, den_w = jax.lax.reduce(
-                    (demand, den),
-                    (jnp.int32(0), jnp.int32(1)), pick, (1,))
-                return num, den_w
-
-            shape = jax.ShapeDtypeStruct((bucket, len(self._combos)),
-                                         jnp.int32)
+            enable_compile_cache()
             with obs.span("predict.compile", bucket=bucket):
-                fn = jax.jit(port_bound_kernel).lower(shape).compile()
+                fn = lower_port_bound(bucket, *self._mask_i.shape).compile()
             self._stats["device_compiles"] += 1
             self._dev_kernels[bucket] = fn
             return fn
@@ -326,3 +310,41 @@ class BatchPredictor:
         """Counters for the vectorized closed-form backend (wave counts,
         device compiles/fallbacks) — absorbed into service metrics."""
         return {"backend": self.backend, **self._stats}
+
+
+def port_bound_kernel(u, mask, size):
+    """Per block row of ``u`` (B×C int32 μop counts per port combination):
+    the winning min-cut candidate's ``(demand, size)`` over ``mask`` (C×S
+    candidate masks) and ``size`` (S candidate port counts).  Exact:
+    ``demand = u @ mask`` in int32, then an integer cross-multiplication
+    argmax over candidates."""
+    import jax  # noqa: PLC0415
+    import jax.numpy as jnp  # noqa: PLC0415
+
+    demand = u @ mask                           # B×S int32, exact
+    den = jnp.broadcast_to(size, demand.shape)
+
+    def pick(acc, x):
+        an, ad = acc
+        bn, bd = x
+        take = an * bd < bn * ad                # exact ratio compare
+        return (jnp.where(take, bn, an), jnp.where(take, bd, ad))
+
+    return jax.lax.reduce((demand, den), (jnp.int32(0), jnp.int32(1)),
+                          pick, (1,))
+
+
+def lower_port_bound(rows: int, combos: int, candidates: int,
+                     sharding=None):
+    """Lower :func:`port_bound_kernel` for a ``rows``-block bucket
+    (``jax.stages.Lowered``; ``.compile()`` it).  ``sharding`` places the
+    operands (default: jax's default device) — a sharding on a described
+    TPU device compiles for a chip that is not attached."""
+    import jax  # noqa: PLC0415
+    import jax.numpy as jnp  # noqa: PLC0415
+
+    sd = jax.ShapeDtypeStruct
+    return jax.jit(port_bound_kernel).lower(
+        sd((rows, combos), jnp.int32, sharding=sharding),
+        sd((combos, candidates), jnp.int32, sharding=sharding),
+        sd((candidates,), jnp.int32, sharding=sharding))

@@ -155,6 +155,36 @@ def test_device_backends_on_interesting_wave(backend):
                         backend=backend)
 
 
+@pytest.mark.parametrize("platform,want", [("tpu", "jax"), ("cpu", "numpy")])
+def test_platform_picks_wave_backend(monkeypatch, platform, want):
+    """With no backend given, the observed platform picks the kernel: the
+    jax scan on a TPU, numpy elsewhere; an explicit backend still wins."""
+    pytest.importorskip("jax")
+    from repro.core import device_mesh
+    monkeypatch.delenv("REPRO_SIM_BACKEND", raising=False)
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: platform)
+    assert SimMachine(SIM_SKL, TEST_ISA).batch_machine().backend == want
+    assert SimMachine(SIM_SKL, TEST_ISA, backend="numpy").batch_machine() \
+        .backend == "numpy"
+
+
+def test_pallas_backend_refused_on_tpu(monkeypatch):
+    """On a TPU the pallas backend fails at construction with Mosaic's
+    reason, through SimMachine too — never interpret mode on the chip and
+    never a silent fallback to another kernel."""
+    pytest.importorskip("jax")
+    from repro.core import batch_sim, device_mesh
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="dynamic_slice"):
+        BatchSimMachine(SIM_SKL, TEST_ISA, backend="pallas")
+    with pytest.raises(NotImplementedError,
+                       match=batch_sim.PALLAS_TPU_REFUSAL[:40]):
+        SimMachine(SIM_SKL, TEST_ISA, backend="pallas").batch_machine()
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: "cpu")
+    assert BatchSimMachine(SIM_SKL, TEST_ISA, backend="pallas").backend \
+        == "pallas"
+
+
 def test_unknown_instruction_raises_keyerror_like_scalar():
     b = BatchSimMachine(SIM_SKL, TEST_ISA)
     with pytest.raises(KeyError):

@@ -10,7 +10,10 @@ path).  True multi-device behavior — mesh sharding, bit-identity at 2 and
 import pytest
 
 from repro.core.batch_sim import BatchSimMachine
-from repro.core.device_mesh import (ENV_DEVICES, dispatch_lock, jax_devices,
+from repro.core import device_mesh
+from repro.core.device_mesh import (COMPILE_CACHE_DIR, ENV_COMPILE_CACHE,
+                                    ENV_DEVICES, dispatch_lock,
+                                    enable_compile_cache, jax_devices,
                                     lane_mesh, partition, resolve_devices)
 from repro.core.isa import TEST_ISA
 from repro.core.machine import RegPool, independent_seq
@@ -185,3 +188,46 @@ def test_characterize_engine_stats_with_device_snapshot():
     assert es["requests"] > 0
     assert isinstance(es["device"], dict)
     assert es["device"].get("backend") == "jax"
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache: where it lives, and when it is turned on
+# ---------------------------------------------------------------------------
+
+
+def _record_config_updates(monkeypatch) -> list:
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: calls.append((name, val)))
+    return calls
+
+
+@pytest.mark.parametrize("platform", [None, "cpu"])
+def test_enable_compile_cache_sets_nothing_off_tpu(monkeypatch, platform):
+    monkeypatch.delenv(ENV_COMPILE_CACHE, raising=False)
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: platform)
+    calls = _record_config_updates(monkeypatch)
+    assert enable_compile_cache() is None
+    assert calls == []
+
+
+def test_enable_compile_cache_leaves_env_dir_to_jax(monkeypatch, tmp_path):
+    monkeypatch.setenv(ENV_COMPILE_CACHE, str(tmp_path))
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: "tpu")
+    calls = _record_config_updates(monkeypatch)
+    assert enable_compile_cache() == str(tmp_path)
+    assert calls == []                           # JAX reads the variable
+
+
+def test_enable_compile_cache_on_tpu_uses_checkout_dir(monkeypatch):
+    monkeypatch.delenv(ENV_COMPILE_CACHE, raising=False)
+    monkeypatch.setattr(device_mesh, "device_platform", lambda: "tpu")
+    calls = _record_config_updates(monkeypatch)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(compilation_cache, "reset_cache", lambda: None)
+    assert enable_compile_cache() == str(COMPILE_CACHE_DIR)
+    assert ("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR)) in calls
+    # a fixed directory inside the checkout, the same every run
+    assert COMPILE_CACHE_DIR.name == ".jax_cache"
+    assert (COMPILE_CACHE_DIR.parent / "src" / "repro" / "core"
+            / "device_mesh.py").exists()

@@ -22,27 +22,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
 SRC = str(Path(__file__).resolve().parents[1] / "src")
-
-
-def _modern_jax() -> bool:
-    """The sharded-training tests drive the modern mesh API
-    (``jax.sharding.AxisType`` / ``jax.set_mesh`` / ``jax.shard_map``),
-    which older jax releases (<= 0.4.x) don't ship."""
-    try:
-        import jax  # noqa: PLC0415
-    except ImportError:
-        return False
-    return (hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh")
-            and hasattr(jax, "shard_map"))
-
-
-needs_modern_jax = pytest.mark.skipif(
-    not _modern_jax(),
-    reason="jax.sharding.AxisType / jax.set_mesh / jax.shard_map "
-           "unavailable in this jax version")
 
 
 def run_py(code: str, devices: int = 4, timeout: int = 480) -> str:
@@ -173,7 +153,6 @@ print("CAMPAIGN_MESH_OK")
     assert "CAMPAIGN_MESH_OK" in out
 
 
-@needs_modern_jax
 def test_moe_shard_map_matches_dense():
     out = run_py("""
 import jax, jax.numpy as jnp, numpy as np
@@ -198,7 +177,6 @@ print("MOE_OK", float(l_sh), float(l_ref))
     assert "MOE_OK" in out
 
 
-@needs_modern_jax
 def test_pipeline_matches_sequential():
     out = run_py("""
 import jax, jax.numpy as jnp, numpy as np
@@ -229,7 +207,6 @@ print("PIPE_OK")
     assert "PIPE_OK" in out
 
 
-@needs_modern_jax
 def test_compressed_psum_close_to_exact():
     out = run_py("""
 import jax, jax.numpy as jnp, numpy as np
@@ -254,7 +231,6 @@ print("PSUM_OK", err, scale)
     assert "PSUM_OK" in out
 
 
-@needs_modern_jax
 def test_seq_sharded_decode_matches_unsharded():
     out = run_py("""
 import jax, jax.numpy as jnp, numpy as np
@@ -282,7 +258,6 @@ print("DECODE_OK")
     assert "DECODE_OK" in out
 
 
-@needs_modern_jax
 def test_production_mesh_shapes():
     out = run_py("""
 from repro.launch.mesh import make_production_mesh
@@ -295,7 +270,6 @@ print("MESH_OK", m1.axis_names, m2.axis_names)
     assert "MESH_OK" in out
 
 
-@needs_modern_jax
 def test_train_step_on_small_mesh():
     """Two sharded train steps on a 2x2 mesh (full jit path with
     in_shardings + donation), loss finite and decreasing-ish."""
@@ -320,7 +294,6 @@ print("TRAIN_MESH_OK", losses[-1][1])
     assert "TRAIN_MESH_OK" in out
 
 
-@needs_modern_jax
 def test_vocab_parallel_ce_matches_gather():
     out = run_py("""
 import jax, jax.numpy as jnp
