@@ -19,7 +19,10 @@ Without --connect, an in-process service is started over --models
 With --trace-report, no block or service is needed: the argument is a
 trace file written by repro.obs.export (Chrome trace JSON or JSONL; see
 README §Observability) and the output is the per-wave bottleneck
-attribution table from repro.analysis.wave_report::
+attribution table from repro.analysis.wave_report (stage shares, lock
+wait, slowest waves; no device tracks: a jax.profiler trace taken with
+the tracer enabled carries the same spans beside the device's own
+operations)::
 
     PYTHONPATH=src python scripts/analyze.py --trace-report run.trace.json
 
@@ -80,7 +83,10 @@ def main(argv=None) -> int:
                     help="print raw JSON responses")
     ap.add_argument("--trace-report", metavar="TRACE",
                     help="summarize a repro.obs trace file (Chrome JSON or "
-                         "JSONL) instead of predicting a block")
+                         "JSONL): stage shares, lock-wait share and the "
+                         "slowest waves, instead of predicting a block; "
+                         "device time is in a jax.profiler trace, which "
+                         "carries the same spans")
     ap.add_argument("--top-waves", type=int, default=5, metavar="K",
                     help="slowest waves to list in --trace-report "
                          "(default 5)")

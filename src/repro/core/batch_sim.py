@@ -75,7 +75,6 @@ from repro.core.uarch_compile import (F_HAS_SR, F_PRESENT, TEMP_BASE,
                                       CompiledUArch, UopTableIndex,
                                       compile_uarch)
 from repro.faults import plan as faults
-from repro.faults.tolerance import StragglerDetector
 from repro.obs import tracer as obs
 
 # producer descriptor kinds (recipe-time)
@@ -388,11 +387,14 @@ class BatchSimMachine:
 
         With tracing on (``REPRO_TRACE=1``, see :mod:`repro.obs`) each
         wave emits a ``wave.run_batch`` span with per-phase children
-        (``wave.lower`` / ``wave.pack`` / ``wave.kernel`` /
-        ``wave.dispatch`` / ``wave.extract``), lock-wait spans
-        (``wave.lock_wait`` / ``wave.dispatch_lock_wait``) measured
-        separately from the work they guard, and per-device kernel spans
-        on ``device:<id>`` tracks."""
+        (``wave.lower`` / ``wave.pack`` / ``wave.kernel`` (host kernels) /
+        ``wave.dispatch`` / ``wave.result_wait`` / ``wave.extract``) and
+        lock-wait spans (``wave.lock_wait`` / ``wave.dispatch_lock_wait``)
+        measured separately from the work they guard.  ``wave.dispatch``
+        carries the device kernels' ``steps`` (serial scan steps),
+        ``slots`` (lane-steps, padding included) and ``rows`` (real μop
+        rows).  Traced and untraced waves dispatch the same way; the
+        kernels' device time is in the profiler trace."""
         with obs.span("wave.run_batch", lanes=len(codes),
                       backend=self.backend):
             return self._run_batch(codes, kernel_lock)
@@ -1104,6 +1106,7 @@ class BatchSimMachine:
         host packing or result waits."""
         from collections import deque  # noqa: PLC0415
         dev = self._device_exec(kind)
+        traced = obs.enabled()
         pending: deque = deque()
         jobs: list = []
         try:
@@ -1116,6 +1119,7 @@ class BatchSimMachine:
                                       [_fault_key(codes[i]) for i in c],
                                       backend=kind)
                 jobs = []
+                steps = slots = rows = 0
                 with obs.span("wave.pack", lanes=len(c)) as psp:
                     if faults.active():
                         faults.check("wave.pack", backend=kind)
@@ -1128,10 +1132,19 @@ class BatchSimMachine:
                         slot = dev.acquire(S0, len(sc), R0)
                         pk = self._pack_chunk(sc, progs, bufs=slot.bufs)
                         jobs.append((pk, slot))
+                        if traced:
+                            # the bucket's serial steps, its lane-steps
+                            # (padding included) and the real μop rows
+                            E, S = slot.bufs[0].shape
+                            steps += S
+                            slots += E * S
+                            rows += sum(progs[i].n_rows for i in sc)
                     psp.set(shards=len(jobs))
                 if not jobs:
                     continue
-                with obs.span("wave.dispatch", shards=len(jobs)):
+                with obs.span("wave.dispatch", shards=len(jobs)) as dsp:
+                    if traced:
+                        dsp.set(steps=steps, slots=slots, rows=rows)
                     futs = dev.dispatch(jobs, kernel_lock)
                 pending.append((jobs, futs))
                 while len(pending) > 1:
@@ -1234,39 +1247,19 @@ class _DeviceExec:
         self._pool = None
         self._lock = threading.Lock()   # guards slot leasing / ring LRU
         self._rings: dict = {}   # bucket -> slot list (LRU by bucket)
-        # per-device kernel wall time + straggler EWMA, fed by the traced
-        # kernel path (the observe hook on _run_kernel); flagged outliers
-        # surface through stats() and the analyze.py wave report
-        self.kernel_ns: dict = {}      # "device:<id>" -> total kernel ns
-        self.straggler = StragglerDetector()
-
-    def _observe(self, tracks, dur_ns: int) -> None:
-        """Traced-kernel callback (runs on a pool thread): accumulate
-        per-device kernel time and feed the straggler EWMA with each
-        device's share of the shard interval."""
-        with self._lock:
-            for label, _ in tracks:
-                self.kernel_ns[label] = self.kernel_ns.get(label, 0) \
-                    + dur_ns
-                self.straggler.observe(label, dur_ns / 1e9)
 
     def stats(self) -> dict:
-        out = {"backend": self.kind, "compiles": self.compiles,
-               "kernel_calls": self.kernel_calls,
-               "buckets": sorted(self.buckets),
-               "mesh": self.mesh_mode,
-               "devices": [d.id for d in self.devices],
-               "per_device": {
-                   did: {"compiles": c["compiles"],
-                         "kernel_calls": c["kernel_calls"],
-                         "lanes": c["lanes"],
-                         "buckets": sorted(c["buckets"])}
-                   for did, c in self.per_device.items()}}
-        with self._lock:
-            if self.kernel_ns:   # only traced waves populate these
-                out["kernel_ns"] = dict(sorted(self.kernel_ns.items()))
-                out["stragglers"] = self.straggler.snapshot()
-        return out
+        return {"backend": self.kind, "compiles": self.compiles,
+                "kernel_calls": self.kernel_calls,
+                "buckets": sorted(self.buckets),
+                "mesh": self.mesh_mode,
+                "devices": [d.id for d in self.devices],
+                "per_device": {
+                    did: {"compiles": c["compiles"],
+                          "kernel_calls": c["kernel_calls"],
+                          "lanes": c["lanes"],
+                          "buckets": sorted(c["buckets"])}
+                    for did, c in self.per_device.items()}}
 
     # -- lane sharding --------------------------------------------------
     def shard(self, chunk, progs) -> list:
@@ -1403,7 +1396,6 @@ class _DeviceExec:
             faults.check("device.dispatch", backend=self.kind)
         pool = self._get_pool()
         M, P = self.comp.mask_table.shape
-        traced = obs.enabled()
         calls = []
         for pk, slot in jobs:
             E, S = pk.issue.shape
@@ -1431,24 +1423,10 @@ class _DeviceExec:
                 self.compiles += 1
             self.buckets.add((S, E, R))
             self.kernel_calls += 1
-            # per-device kernel spans: each participating device's track
-            # gets the shard's kernel interval with its real lane share
-            tracks = tuple(
-                (f"device:{d.id}",
-                 max(0, min(pk.E - k * e_dev, e_dev)))
-                for k, d in enumerate(devs)) if traced else ()
             calls.append((fn, (pk.issue, pk.mask, pk.lat, pk.blk, pk.valid,
-                               pk.prod, pk.delta, lut), tracks))
+                               pk.prod, pk.delta, lut)))
         with obs.wait_lock(self.dispatch_lock, "wave.dispatch_lock_wait"):
-            # untraced waves keep the legacy 2-arg call (tests monkeypatch
-            # _run_kernel with that signature to inject kernel failures)
-            if traced:
-                futs = [pool.submit(_run_kernel, fn, args, tracks,
-                                    self._observe)
-                        for fn, args, tracks in calls]
-            else:
-                futs = [pool.submit(_run_kernel, fn, args)
-                        for fn, args, _ in calls]
+            futs = [pool.submit(_run_kernel, fn, args) for fn, args in calls]
         # the slots stay leased: ``_finalize_device`` releases them only
         # after extraction, which reads the slots' vis buffers
         return futs
@@ -1488,29 +1466,13 @@ def _abort_jobs(jobs, futs) -> None:
         slot.release()
 
 
-def _run_kernel(fn, args, tracks=(), observe=None):
+def _run_kernel(fn, args):
     """Pool worker: execute one compiled shard kernel and realize its
     outputs on the host (so finalization only touches host arrays; the
-    packing buffers themselves stay leased until extraction).
-
-    ``tracks`` — when tracing is on — attributes the kernel interval to
-    every participating device's ``device:<id>`` trace track with that
-    device's real lane share (how per-device timelines and imbalance
-    appear in the wave report); ``observe`` additionally feeds the
-    executor's per-device kernel-time counters and straggler EWMA."""
-    if not tracks:
-        done, counts = fn(*args)
-        return np.asarray(done), np.asarray(counts)
-    import time  # noqa: PLC0415
-    t0 = time.perf_counter_ns()
+    packing buffers themselves stay leased until extraction).  Its device
+    time is in the profiler trace, under the program's name."""
     done, counts = fn(*args)
-    out = np.asarray(done), np.asarray(counts)
-    dur = time.perf_counter_ns() - t0
-    for label, lanes in tracks:
-        obs.emit_span("wave.kernel", t0, dur, track=label, lanes=lanes)
-    if observe is not None:
-        observe(tracks, dur)
-    return out
+    return np.asarray(done), np.asarray(counts)
 
 
 # ---------------------------------------------------------------------------

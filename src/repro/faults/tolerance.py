@@ -2,12 +2,7 @@
 restart/elastic decisions.
 
 Folded in from the seed-era ``repro.runtime.fault_tolerance`` (a
-deprecation shim remains at the old path). The :class:`StragglerDetector`
-EWMA is wired to real data now: ``BatchSimMachine``'s device executor
-feeds it per-device kernel wall times (see ``device_stats()``), and
-``repro.analysis.wave_report`` runs one over the per-device
-``wave.kernel`` spans of a trace so flagged stragglers show up in
-``scripts/analyze.py --trace-report``. The *decisions* (restart from
+deprecation shim remains at the old path). The *decisions* (restart from
 checkpoint, drop to a smaller mesh, flag stragglers) are pure functions
 so they are testable without hardware.
 """

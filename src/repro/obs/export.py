@@ -3,9 +3,7 @@
 ``chrome_trace`` renders a :class:`repro.obs.tracer.Tracer`'s events into
 the `trace-event format`__ understood by Perfetto and ``chrome://tracing``:
 
-* one track per real thread (named after ``threading.Thread.name``),
-* one synthetic track per named track (``device:0`` … — per-device kernel
-  timelines),
+* one track per thread (named after ``threading.Thread.name``),
 * counter tracks (``ph == "C"``) for sampled values such as cache
   hit-rate and wave width.
 
@@ -23,20 +21,11 @@ from typing import Dict, List, Optional
 
 from repro.obs.tracer import Tracer, get_tracer
 
-#: synthetic tid range for named tracks, far above real thread idents' use
-#: as display sort keys once remapped
-_TRACK_TID_BASE = 1 << 20
-
-
-def _tid_map(tracer: Tracer) -> Dict[object, int]:
-    """Stable mapping from event tid keys (thread idents and track names)
-    to small integer tids for the viewer."""
-    mapping: Dict[object, int] = {}
-    for i, ident in enumerate(sorted(tracer.thread_names()), start=1):
-        mapping[ident] = i
-    for j, track in enumerate(sorted(tracer.tracks())):
-        mapping[track] = _TRACK_TID_BASE + j
-    return mapping
+def _tid_map(tracer: Tracer) -> Dict[int, int]:
+    """Stable mapping from thread idents to small integer tids for the
+    viewer."""
+    return {ident: i for i, ident in
+            enumerate(sorted(tracer.thread_names()), start=1)}
 
 
 def chrome_trace(tracer: Optional[Tracer] = None, *,
@@ -53,9 +42,6 @@ def chrome_trace(tracer: Optional[Tracer] = None, *,
     for ident, name in sorted(tr.thread_names().items()):
         events.append({"ph": "M", "name": "thread_name", "pid": pid,
                        "tid": tids[ident], "args": {"name": name}})
-    for track in sorted(tr.tracks()):
-        events.append({"ph": "M", "name": "thread_name", "pid": pid,
-                       "tid": tids[track], "args": {"name": track}})
     for ev in tr.events():
         tid = tids.get(ev["tid"], 0)
         ts = (ev["t0"] - t0) / 1000.0
@@ -87,8 +73,7 @@ def write_jsonl(path, tracer: Optional[Tracer] = None) -> str:
             rec = {"ph": ev["ph"], "name": ev["name"],
                    "ts_us": (ev["t0"] - t0) / 1000.0,
                    "dur_us": ev["dur"] / 1000.0,
-                   "tid": ev["tid"] if isinstance(ev["tid"], str)
-                   else int(ev["tid"]),
+                   "tid": int(ev["tid"]),
                    "args": ev["args"] or {}}
             fh.write(json.dumps(rec) + "\n")
     return str(path)
@@ -99,7 +84,7 @@ def load_events(path) -> List[dict]:
     shape: ``{"ph", "name", "ts_us", "dur_us", "tid", "tid_name", "args"}``.
 
     For Chrome-trace files the thread_name metadata is folded into
-    ``tid_name`` so reports can tell device tracks from worker threads."""
+    ``tid_name``."""
     with open(path) as fh:
         text = fh.read()
     try:
@@ -128,7 +113,6 @@ def load_events(path) -> List[dict]:
         if not line:
             continue
         rec = json.loads(line)
-        tid = rec.get("tid", 0)
-        rec.setdefault("tid_name", tid if isinstance(tid, str) else "")
+        rec.setdefault("tid_name", "")
         out.append(rec)
     return out

@@ -29,10 +29,13 @@ Design notes
   pool.  A ``trace_id`` attribute set on an enclosing span is inherited
   by child spans on the same thread (how server request IDs flow into
   batch-predictor spans without threading them through every signature).
-* ``track=`` pins an event to a named synthetic track (e.g. ``device:0``)
-  instead of the calling thread — the export layer gives each track its
-  own tid + thread-name metadata, which is how per-device kernel
-  timelines appear in Perfetto.
+* Each span also records the thread's CPU time (``time.thread_time_ns``)
+  as ``cpu`` beside ``dur``: for a span on one thread, wall minus CPU is
+  time the thread waited (for the interpreter lock, a lock or the device).
+* While a tracer is enabled and jax can be imported, every span (and every
+  timed lock wait) also enters a ``jax.profiler.TraceAnnotation`` of the
+  same name on the thread that runs it, so a profiler trace carries the
+  program's spans on the device trace's clock, one host line per thread.
 """
 from __future__ import annotations
 
@@ -42,6 +45,22 @@ import time
 from typing import Any, Dict, List, Optional
 
 _now = time.perf_counter_ns
+_cpu = time.thread_time_ns
+
+_ANNOTATION = ()     # jax.profiler.TraceAnnotation, None without jax
+
+
+def _annotation():
+    """The profiler's annotation class, imported on first use by an
+    enabled tracer; ``None`` when jax cannot be imported."""
+    global _ANNOTATION
+    if _ANNOTATION == ():
+        try:
+            from jax.profiler import TraceAnnotation  # noqa: PLC0415
+        except ImportError:
+            TraceAnnotation = None
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class _NullSpan:
@@ -63,16 +82,16 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span: records one complete ("X") event when it exits."""
+    """A live span: records one complete ("X") event when it exits, and
+    holds a profiler annotation of the same name open meanwhile."""
 
-    __slots__ = ("_tracer", "name", "args", "track", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_ann", "_t0", "_c0")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 args: Optional[Dict[str, Any]], track: Optional[str]):
+                 args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self.name = name
         self.args = args
-        self.track = track
 
     def set(self, **attrs) -> "_Span":
         """Attach (or overwrite) key/value attributes mid-span."""
@@ -84,11 +103,21 @@ class _Span:
 
     def __enter__(self):
         self._tracer._stack().append(self)
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._c0 = _cpu()
         self._t0 = _now()
         return self
 
     def __exit__(self, *exc):
         t1 = _now()
+        c1 = _cpu()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] is self:
@@ -102,7 +131,8 @@ class _Span:
                     args = dict(args) if args else {}
                     args["trace_id"] = a["trace_id"]
                     break
-        tr._emit("X", self.name, self._t0, t1 - self._t0, args, self.track)
+        tr._emit("X", self.name, self._t0, t1 - self._t0, args,
+                 c1 - self._c0)
         return False
 
 
@@ -130,10 +160,8 @@ class _LockWait:
         if tr is None:
             self._lock.__enter__()
             return self
-        t0 = _now()
-        self._lock.__enter__()
-        t1 = _now()
-        tr._emit("X", self._name, t0, t1 - t0, None, None)
+        with _Span(tr, self._name, None):
+            self._lock.__enter__()
         return self
 
     def __exit__(self, *exc):
@@ -155,23 +183,22 @@ class Tracer:
         self._events: List[dict] = []
         self._local = threading.local()
         self._threads: Dict[int, str] = {}
-        self._tracks: Dict[str, None] = {}
 
     # -- hot path ------------------------------------------------------
-    def span(self, name: str, *, track: Optional[str] = None, **attrs):
+    def span(self, name: str, **attrs):
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, attrs or None, track)
+        return _Span(self, name, attrs or None)
 
-    def instant(self, name: str, *, track: Optional[str] = None, **attrs):
+    def instant(self, name: str, **attrs):
         """A zero-duration marker event."""
         if self.enabled:
-            self._emit("i", name, _now(), 0, attrs or None, track)
+            self._emit("i", name, _now(), 0, attrs or None)
 
-    def counter(self, name: str, value, *, track: Optional[str] = None):
+    def counter(self, name: str, value):
         """A sampled counter value (a counter track in Perfetto)."""
         if self.enabled:
-            self._emit("C", name, _now(), 0, {"value": value}, track)
+            self._emit("C", name, _now(), 0, {"value": value})
 
     def wait_lock(self, lock, name: str = "lock.wait"):
         if not self.enabled:
@@ -186,23 +213,15 @@ class Tracer:
         return st
 
     def _emit(self, ph: str, name: str, t0: int, dur: int,
-              args: Optional[dict], track: Optional[str]):
-        if track is None:
-            tid = threading.get_ident()
-            if tid not in self._threads:
-                self._threads[tid] = threading.current_thread().name
-        else:
-            tid = track
-            self._tracks[track] = None
-        self._events.append({"ph": ph, "name": name, "t0": t0, "dur": dur,
-                             "tid": tid, "args": args})
-
-    def emit_span(self, name: str, t0_ns: int, dur_ns: int, *,
-                  track: Optional[str] = None, **attrs):
-        """Record an already-timed interval (used by pool workers that
-        measure with raw clocks and attribute the span to a device track)."""
-        if self.enabled:
-            self._emit("X", name, t0_ns, dur_ns, attrs or None, track)
+              args: Optional[dict], cpu: Optional[int] = None):
+        tid = threading.get_ident()
+        if tid not in self._threads:
+            self._threads[tid] = threading.current_thread().name
+        ev = {"ph": ph, "name": name, "t0": t0, "dur": dur, "tid": tid,
+              "args": args}
+        if cpu is not None:
+            ev["cpu"] = cpu
+        self._events.append(ev)
 
     def events(self) -> List[dict]:
         """The raw event list (internal schema; see export.py for the
@@ -212,14 +231,10 @@ class Tracer:
     def clear(self):
         self._events.clear()
         self._threads.clear()
-        self._tracks.clear()
         self.t0_ns = _now()
 
     def thread_names(self) -> Dict[int, str]:
         return dict(self._threads)
-
-    def tracks(self) -> List[str]:
-        return list(self._tracks)
 
 
 def _from_env() -> Tracer:
@@ -249,23 +264,23 @@ def enabled() -> bool:
 
 # Module-level helpers: the instrumented call sites use these.  Each is a
 # single global load + truth test when tracing is off.
-def span(name: str, *, track: Optional[str] = None, **attrs):
+def span(name: str, **attrs):
     t = _GLOBAL
     if not t.enabled:
         return NULL_SPAN
-    return _Span(t, name, attrs or None, track)
+    return _Span(t, name, attrs or None)
 
 
-def instant(name: str, *, track: Optional[str] = None, **attrs):
+def instant(name: str, **attrs):
     t = _GLOBAL
     if t.enabled:
-        t._emit("i", name, _now(), 0, attrs or None, track)
+        t._emit("i", name, _now(), 0, attrs or None)
 
 
-def counter(name: str, value, *, track: Optional[str] = None):
+def counter(name: str, value):
     t = _GLOBAL
     if t.enabled:
-        t._emit("C", name, _now(), 0, {"value": value}, track)
+        t._emit("C", name, _now(), 0, {"value": value})
 
 
 def wait_lock(lock, name: str = "lock.wait"):
@@ -274,9 +289,3 @@ def wait_lock(lock, name: str = "lock.wait"):
         return _LockWait(lock, name, None)
     return _LockWait(lock, name, t)
 
-
-def emit_span(name: str, t0_ns: int, dur_ns: int, *,
-              track: Optional[str] = None, **attrs):
-    t = _GLOBAL
-    if t.enabled:
-        t._emit("X", name, t0_ns, dur_ns, attrs or None, track)

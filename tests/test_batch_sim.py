@@ -865,3 +865,85 @@ def test_legacy_measure_results_unchanged_by_batch_default():
     c1, c2 = s.run(seq * 10), s.run(seq * 110)
     assert got.cycles == (c2.cycles - c1.cycles) / 100
     assert as_engine(m).stats.executions == 1
+
+
+# ---------------------------------------------------------------------------
+# tracing: the dispatch counters, one dispatch path, the kernels' names
+# ---------------------------------------------------------------------------
+
+
+def _traced(fn):
+    from repro.obs.tracer import Tracer, set_tracer
+    tr = Tracer(enabled=True)
+    prev = set_tracer(tr)
+    try:
+        return fn(), tr.events()
+    finally:
+        set_tracer(prev)
+
+
+def test_dispatch_span_counts_match_packed_shards(monkeypatch):
+    """``wave.dispatch`` carries the shards' bucket steps, lane-steps and
+    real μop rows, as counted by hand from the packed buffers."""
+    pytest.importorskip("jax")
+    from repro.core.batch_sim import _DeviceExec
+    m = BatchSimMachine(SIM_SKL, TEST_ISA, backend="jax", min_lanes=1)
+    m._device_exec("jax").n_workers = 3      # several shards per chunk
+    seen = []
+    real = _DeviceExec.dispatch
+
+    def dispatch(self, jobs, kernel_lock=None):
+        seen.append((len(jobs),
+                     sum(pk.issue.shape[1] for pk, _ in jobs),
+                     sum(pk.issue.size for pk, _ in jobs),
+                     sum(int(pk.valid.sum()) for pk, _ in jobs)))
+        return real(self, jobs, kernel_lock)
+
+    monkeypatch.setattr(_DeviceExec, "dispatch", dispatch)
+    rng = random.Random(5)
+    lanes = 3 * _DeviceExec._SHARD_MIN_LANES
+    codes = [independent_seq(TEST_ISA[rng.choice(["ADD_R64_R64",
+                                                  "IMUL_R64_R64"])],
+                             RegPool(), rng.randint(2, 3))
+             * rng.randint(10, 20) for _ in range(lanes)]   # one chunk
+    _, events = _traced(lambda: m.run_batch(codes))
+    got = [(e["args"]["shards"], e["args"]["steps"], e["args"]["slots"],
+            e["args"]["rows"])
+           for e in events if e["name"] == "wave.dispatch"]
+    assert got == seen
+    assert max(n for n, *_ in seen) > 1
+    assert all(0 < rows <= slots for *_, slots, rows in seen)
+
+
+def test_traced_and_untraced_waves_take_one_path():
+    """Tracing changes neither the answers nor the executor's stats."""
+    pytest.importorskip("jax")
+    codes = _interesting_wave(TEST_ISA)
+    plain = BatchSimMachine(SIM_SKL, TEST_ISA, backend="jax", min_lanes=1)
+    want = plain.run_batch(codes)
+    traced = BatchSimMachine(SIM_SKL, TEST_ISA, backend="jax", min_lanes=1)
+    got, events = _traced(lambda: traced.run_batch(codes))
+    assert [(c.cycles, c.port_uops) for c in got] == \
+        [(c.cycles, c.port_uops) for c in want]
+    a, b = plain.device_stats(), traced.device_stats()
+    assert set(a) == set(b)
+    assert a["kernel_calls"] == b["kernel_calls"]
+    assert {k: set(v) for k, v in a["per_device"].items()} == \
+        {k: set(v) for k, v in b["per_device"].items()}
+    names = {e["name"] for e in events}
+    assert {"wave.pack", "wave.dispatch", "wave.result_wait",
+            "wave.extract"} <= names
+    assert all(isinstance(e["tid"], int) for e in events)
+
+
+def test_device_kernel_module_names():
+    """The profiler names a kernel's device time by its HLO module; the
+    benchmark's readers key on these two names."""
+    pytest.importorskip("jax")
+    from repro.core.batch_sim import lower_kernel
+    from repro.service.batch_predictor import lower_port_bound
+    M, P = compile_uarch(SIM_SKL, TEST_ISA).mask_table.shape
+    wave = lower_kernel("jax", 32, 8, 2, M, P).compile().as_text()
+    bound = lower_port_bound(8, 3, 4).compile().as_text()
+    assert wave.startswith("HloModule jit_run,")
+    assert bound.startswith("HloModule jit_port_bound_kernel,")

@@ -523,23 +523,6 @@ def test_straggler_detector_flags_slow_device():
     assert snap["ewma_s"]["device:2"] > 2 * snap["median_s"]
 
 
-def test_wave_report_surfaces_stragglers():
-    from repro.analysis.wave_report import format_wave_report, wave_report
-
-    events = []
-    ts = 0.0
-    for _ in range(5):
-        for dev, dur in (("device:0", 100.0), ("device:1", 100.0),
-                         ("device:2", 1000.0)):
-            events.append({"ph": "X", "name": "wave.kernel", "ts_us": ts,
-                           "dur_us": dur, "tid_name": dev})
-            ts += dur
-    rep = wave_report(events)
-    assert rep["stragglers"]["flagged"] == ["device:2"]
-    text = format_wave_report(rep)
-    assert "stragglers" in text and "device:2" in text
-
-
 def test_runtime_fault_tolerance_shim_warns():
     sys.modules.pop("repro.runtime.fault_tolerance", None)
     with pytest.warns(DeprecationWarning, match="repro.faults.tolerance"):

@@ -7,7 +7,9 @@ The load-bearing guarantees:
     every call site — no allocation on the off path);
   * spans nest per thread and are reentrant across the Campaign pool;
   * the Chrome trace-event export is schema-valid (ph/ts/dur/pid/tid/args,
-    thread-name metadata, counter and device tracks);
+    thread-name metadata, counter tracks);
+  * an enabled tracer's spans are profiler annotations on their own
+    thread's host line, and carry thread CPU time beside wall time;
   * characterize with tracing ON produces byte-identical XML to the
     committed model artifact;
   * the legacy stats dict shapes (EngineStats.as_dict, server stats) are
@@ -15,7 +17,9 @@ The load-bearing guarantees:
 """
 import json
 import logging
+import sys
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
@@ -65,7 +69,6 @@ def test_disabled_is_shared_noop(disabled):
         inner.set(k=1)  # no-op, chainable
     tracer.instant("i", a=1)
     tracer.counter("c", 42)
-    tracer.emit_span("e", 0, 10)
     assert disabled.events() == []
     assert not tracer.enabled()
 
@@ -139,15 +142,95 @@ def test_wait_lock_measures_contention(traced):
     (ev,) = traced.events()
     assert ev["name"] == "wave.lock_wait"
     assert ev["dur"] >= 20e6  # waited >= 20ms, in ns
+    assert ev["cpu"] < ev["dur"] / 2   # blocked, not computing
 
 
-def test_emit_span_on_device_track(traced):
-    tracer.emit_span("wave.kernel", traced.t0_ns, 5000,
-                     track="device:0", lanes=8)
-    (ev,) = traced.events()
-    assert ev["tid"] == "device:0"
-    assert traced.tracks() == ["device:0"]
-    assert ev["args"] == {"lanes": 8}
+def _busy(seconds):
+    t_end = time.perf_counter() + seconds
+    x = 0
+    while time.perf_counter() < t_end:
+        x += 1
+    return x
+
+
+def test_span_records_thread_cpu_time(traced):
+    with tracer.span("busy"):
+        _busy(0.05)
+    with tracer.span("sleep"):
+        time.sleep(0.05)
+    by = {e["name"]: e for e in traced.events()}
+    busy, sleep = by["busy"], by["sleep"]
+    # CPU time never exceeds wall (1 ms for the clocks' own granularity);
+    # a busy loop is mostly CPU even if the scheduler preempts it, a
+    # sleep is almost none
+    assert 0.5 * busy["dur"] <= busy["cpu"] <= busy["dur"] + 1e6
+    assert sleep["dur"] >= 45e6
+    assert sleep["cpu"] <= 0.1 * sleep["dur"]
+
+
+def test_spans_are_profiler_annotations_on_their_threads(traced, tmp_path):
+    """Each thread's spans land on that thread's host line of the
+    profiler trace, nested as they ran; three threads, three lines."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    barrier = threading.Barrier(3)
+
+    def work(i):
+        barrier.wait()
+        with tracer.span(f"outer.{i}"):
+            with tracer.span(f"inner.{i}"):
+                time.sleep(0.01)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    lines = []
+    for line in host.lines:
+        evs = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+               for ev in line.events
+               if ev.name.startswith(("outer.", "inner."))}
+        if evs:
+            lines.append(evs)
+    assert len(lines) == 3
+    for i in range(3):
+        (line,) = [evs for evs in lines if f"outer.{i}" in evs]
+        assert set(line) == {f"outer.{i}", f"inner.{i}"}
+        (a, b), (c, d) = line[f"outer.{i}"], line[f"inner.{i}"]
+        assert a <= c < d <= b
+        assert d - c >= 9e6
+    # the tracer's own events are unchanged by the profiler
+    assert sorted(e["name"] for e in traced.events()) == sorted(
+        f"{k}.{i}" for i in range(3) for k in ("inner", "outer"))
+
+
+def test_enabled_tracer_without_jax(traced, monkeypatch):
+    """Without jax the tracer records its events all the same."""
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    monkeypatch.setattr(tracer, "_ANNOTATION", ())
+    with tracer.span("a", k=1):
+        with tracer.wait_lock(threading.Lock(), "w"):
+            pass
+    assert tracer._ANNOTATION is None
+    evs = traced.events()
+    assert [e["name"] for e in evs] == ["w", "a"]
+    assert all(e["cpu"] >= 0 for e in evs)
 
 
 # ---------------------------------------------------------------------------
@@ -161,8 +244,6 @@ def _populate(tr):
             pass
     tracer.counter("scheduler.wave_width", 4)
     tracer.instant("mesh.partition", devices=0)
-    tracer.emit_span("wave.kernel", tr.t0_ns + 100, 2000,
-                     track="device:1", lanes=4)
 
 
 def test_chrome_trace_schema(traced):
@@ -175,7 +256,7 @@ def test_chrome_trace_schema(traced):
             "args": {"name": "repro-test"}} in meta
     tnames = {e["tid"]: e["args"]["name"] for e in meta
               if e["name"] == "thread_name"}
-    assert "device:1" in tnames.values()
+    assert threading.current_thread().name in tnames.values()
     for ev in evs:
         assert set(ev) >= {"ph", "name", "pid", "tid", "args"}
         assert ev["pid"] == traced.pid
@@ -184,9 +265,9 @@ def test_chrome_trace_schema(traced):
     complete = {e["name"]: e for e in evs if e["ph"] == "X"}
     assert "dur" in complete["scheduler.run"]
     assert complete["scheduler.run"]["args"] == {"plans": 2}
-    # the device-track event landed on the synthetic track tid
-    dev_tid = next(t for t, n in tnames.items() if n == "device:1")
-    assert complete["wave.kernel"]["tid"] == dev_tid
+    # every event sits on its thread's track
+    assert tnames[complete["wave.run_batch"]["tid"]] == \
+        threading.current_thread().name
     (cnt,) = [e for e in evs if e["ph"] == "C"]
     assert cnt["args"] == {"value": 4}
     (inst,) = [e for e in evs if e["ph"] == "i"]
@@ -203,7 +284,8 @@ def test_exporters_roundtrip(traced, tmp_path):
     key = lambda e: (e["name"], e["ph"], round(e["ts_us"], 3))  # noqa: E731
     assert sorted(map(key, a)) == sorted(map(key, b))
     by_name = {e["name"]: e for e in a}
-    assert by_name["wave.kernel"]["tid_name"] == "device:1"
+    assert by_name["wave.run_batch"]["tid_name"] == \
+        threading.current_thread().name
     assert by_name["wave.run_batch"]["dur_us"] >= 0
 
 
@@ -317,7 +399,7 @@ def test_wave_report_attribution(traced, tmp_path):
     assert rep["stages"]["kernel"]["us"] > 0
     shares = [s["share"] for s in rep["stages"].values()]
     assert abs(sum(shares) + rep["lock_wait"]["share"] - 1.0) < 1e-9
-    assert rep["bottleneck"].endswith(("-bound", "imbalanced", "idle"))
+    assert rep["bottleneck"].endswith(("-bound", "idle"))
     assert rep["top_waves"]
     text = format_wave_report(rep)
     assert "bottleneck" in text and "lock_wait" in text
@@ -326,13 +408,6 @@ def test_wave_report_attribution(traced, tmp_path):
 def test_wave_report_device_imbalance():
     from repro.analysis import wave_report as wr
 
-    def dev(track, dur):
-        return {"ph": "X", "name": "wave.kernel", "ts_us": 0.0,
-                "dur_us": dur, "tid": track, "tid_name": track, "args": {}}
-
-    rep = wr.wave_report([dev("device:0", 900.0), dev("device:1", 100.0)])
-    assert rep["device_imbalance"] == pytest.approx(1.8)
-    assert rep["bottleneck"] == "device-imbalanced"
     # lock-bound wins over stage attribution when wait dominates
     rep2 = wr.wave_report([
         {"ph": "X", "name": "wave.kernel", "ts_us": 0.0, "dur_us": 100.0,
