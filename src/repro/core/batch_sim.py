@@ -58,8 +58,11 @@ cells, store-to-load forwarding, move elimination, and zero idioms all
 reduce to per-μop producer row indices.  Because the measurement engine
 submits ``body * n`` unrollings (Algorithm 2), lowering detects the
 periodic steady state — once the machine state signature repeats at a copy
-boundary, the remaining copies are *tiled* with shifted NumPy arrays
-instead of per-μop Python work.
+boundary, the remaining copies are not walked.  The walk appends each
+body's native rows to one set of wave-wide lists; one wave pass then
+converts them and *tiles* every body's remaining copies with a gather per
+tensor over the whole wave, instead of per-μop Python work or per-body
+NumPy calls.
 """
 from __future__ import annotations
 
@@ -94,9 +97,14 @@ BACKENDS = ("numpy", "jax", "pallas")
 # ``batch_sim.min_lanes_crossover``); results are bit-identical either way.
 DEFAULT_MIN_LANES = 4
 
-# lowering-cache bound (distinct (body, unroll-count) programs).  A full
-# characterization stays in the hundreds; the bound exists so service-backed
-# machines fed unbounded query streams cannot grow without limit.
+# lowering-cache bound (distinct (body, unroll-count) programs).  A cold
+# campaign over the test ISA caches ~1,700 per machine; the bound exists so
+# service-backed machines fed unbounded query streams cannot grow without
+# limit.  Cached programs are views into their wave's arrays, so an entry
+# holds its whole wave's rows (~4 MB for a campaign wave of ~100K rows)
+# until the wave's last entry is evicted; a wave's entries enter and age
+# together, so the bound holds about the waves its newest 4096 programs
+# came from, plus at most the waves of entries refreshed by later hits.
 DEFAULT_LOWER_CACHE = 4096
 
 # lane-block width for the pallas kernel grid (the TPU lane dimension)
@@ -183,6 +191,129 @@ class _Prog:
         self.delta = delta
         self.finals = finals
         self.max_r = max_r
+
+
+class _WaveRows:
+    """A wave's native μop rows as flat Python lists: ``_lower``'s row loop
+    appends every walked body's rows here, and :func:`_materialize` turns
+    them into arrays once per wave.  Producer pairs are flat too:
+    ``prod_end[j]`` is the end of row ``j``'s producers in ``prod``, and
+    the rare nonzero deltas (partial-register stalls) are kept sparse as
+    ``(delta_at, delta)`` positions in ``prod``."""
+    __slots__ = ("issue", "mask", "lat", "blk", "vis", "prod", "prod_end",
+                 "delta_at", "delta")
+
+    def __init__(self):
+        self.issue: list = []
+        self.mask: list = []
+        self.lat: list = []
+        self.blk: list = []
+        self.vis: list = []
+        self.prod: list = []
+        self.prod_end: list = []
+        self.delta_at: list = []
+        self.delta: list = []
+
+
+class _Walk:
+    """One lowered body as ``_lower``'s loop leaves it for the wave pass.
+
+    ``start``/``n_nat``: its native rows in the :class:`_WaveRows`;
+    ``tile``: ``None`` or ``(c0, c1, s0, d_rows, d_issue)`` — copies
+    ``c0..c1`` are the steady-state period, occupying native rows
+    ``s0..n_nat``, and every later copy repeats them shifted by ``d_rows``
+    rows and ``d_issue`` issue cycles per period; ``rows``: rows of the
+    whole unrolling (native plus tiled); ``cuts``: ``(copies, rows,
+    finals)`` per requested copy count."""
+    __slots__ = ("start", "n_nat", "tile", "rows", "cuts")
+
+    def __init__(self, start, n_nat, tile, rows, cuts):
+        self.start = start
+        self.n_nat = n_nat
+        self.tile = tile
+        self.rows = rows
+        self.cuts = cuts
+
+
+def _materialize(acc: _WaveRows, walks) -> tuple:
+    """The wave pass: the wave's native rows become int32 arrays once, and
+    every body's steady-state copies are unrolled by one gather per tensor
+    over the whole wave.  The output is laid out body after body, each as
+    segments of rows — its native rows (``q = 0``), then one segment per
+    period ``q = 1, 2, …`` taking native rows ``s0..`` shifted by ``q``
+    periods: ``q·d_issue`` issue cycles, and ``q·d_rows`` rows on every real
+    producer.  Returns one ``{copies: _Prog}`` per walk, in order, and the
+    rows written.
+
+    Each :class:`_Prog` is a view into the wave's arrays (shorter cuts are
+    prefixes of the same rows: causality); what that costs the lowering
+    cache is at ``DEFAULT_LOWER_CACHE``."""
+    i32 = np.int32
+    # int32 throughout: every simulated quantity fits (cycles, rows, counts
+    # < 2^31 - 1 — the device kernels reserve INT32_MAX as the
+    # disallowed-port dispatch sentinel) and runs int32 natively
+    issue = np.array(acc.issue, i32)
+    mask = np.array(acc.mask, i32)
+    lat = np.array(acc.lat, i32)
+    blk = np.array(acc.blk, i32)
+    vis = np.array(acc.vis, i32)
+    n, B = issue.size, len(walks)
+    ends = np.array(acc.prod_end, np.int64)
+    width = np.diff(ends, prepend=0)            # producers per native row
+    starts = np.array([w.start for w in walks], np.int64)
+    n_nat = np.array([w.n_nat for w in walks], np.int64)
+    # each body's read width: its widest native row, at least 1
+    max_r = np.ones(B, np.int64)
+    real = np.flatnonzero(n_nat)
+    if real.size:
+        max_r[real] = np.maximum(np.maximum.reduceat(width, starts[real]), 1)
+    R = int(max_r.max(initial=1))
+    prod = np.full(n * R, -1, i32)
+    delta = np.zeros(n * R, i32)
+    if n and ends[-1]:
+        at = np.arange(ends[-1]) + np.repeat(np.arange(0, n * R, R)
+                                             - (ends - width), width)
+        prod[at] = np.array(acc.prod, i32)
+        if acc.delta_at:
+            delta[at[np.array(acc.delta_at, np.int64)]] = np.array(
+                acc.delta, i32)
+    prod = prod.reshape(n, R)
+    delta = delta.reshape(n, R)
+    # segments: per body its native rows, then `full` periods of `per` rows
+    # and a last partial one of `left` rows
+    lens = np.array([w.rows for w in walks], np.int64)
+    s0 = np.zeros(B, np.int64)
+    d_rows = np.zeros(B, np.int64)
+    d_issue = np.zeros(B, np.int64)
+    for b, w in enumerate(walks):
+        if w.tile is not None:
+            _, _, s0[b], d_rows[b], d_issue[b] = w.tile
+    per = np.maximum(d_rows, 1)        # d_rows 0: nothing is tiled
+    full, left = np.divmod(lens - n_nat, per)
+    nseg = 1 + full + (left > 0)
+    sb = np.repeat(np.arange(B), nseg)                  # segment -> body
+    q = np.arange(nseg.sum()) - np.repeat(np.cumsum(nseg) - nseg, nseg)
+    seg_len = np.where(q == 0, n_nat[sb],
+                       np.where(q <= full[sb], per[sb], left[sb]))
+    seg_src = starts[sb] + np.where(q == 0, 0, s0[sb])
+    src = np.arange(int(lens.sum())) + np.repeat(
+        seg_src - (np.cumsum(seg_len) - seg_len), seg_len)
+    issue = issue.take(src)
+    issue += np.repeat((q * d_issue[sb]).astype(i32), seg_len)
+    prod = prod.take(src, axis=0)
+    np.add(prod, np.repeat((q * d_rows[sb]).astype(i32), seg_len)[:, None],
+           out=prod, where=prod >= 0)
+    mask, lat, blk, vis = (mask.take(src), lat.take(src), blk.take(src),
+                           vis.take(src))
+    delta = delta.take(src, axis=0)
+    made = []
+    for o, r, w in zip((np.cumsum(lens) - lens).tolist(), max_r.tolist(),
+                       walks):
+        made.append({c: _Prog(m, issue[o:o + m], mask[o:o + m],
+                              lat[o:o + m], blk[o:o + m], vis[o:o + m],
+                              prod[o:o + m, :r], delta[o:o + m, :r], fin, r)
+                     for c, m, fin in w.cuts})
+    return made, src.size
 
 
 def _body_period(ids) -> int:
@@ -531,17 +662,27 @@ class BatchSimMachine:
         of the same tensors (causality).  Holds the machine's host lock:
         the cache LRU (pop/reinsert/evict) and the recipe memo are shared
         mutable state across concurrent ``run_batch`` callers.  Traced as
-        a ``wave.lower`` span carrying this wave's cache hit/miss delta."""
+        a ``wave.lower`` span carrying this wave's cache hit/miss delta,
+        the rows the lowering loop walked (``rows_native``) and the rows
+        the wave pass materialized (``rows_out``)."""
         stats = self.lowering_stats
         h0, m0 = stats["hits"], stats["misses"]
         with obs.span("wave.lower",
                       lanes=sum(len(c) for c in batched)) as sp, \
                 self._host_lock:
-            progs = self._lower_wave_locked(codes, batched)
-            sp.set(hits=stats["hits"] - h0, misses=stats["misses"] - m0)
+            progs, rows_native, rows_out = self._lower_wave_locked(codes,
+                                                                   batched)
+            sp.set(hits=stats["hits"] - h0, misses=stats["misses"] - m0,
+                   rows_native=rows_native, rows_out=rows_out)
         return progs
 
-    def _lower_wave_locked(self, codes, batched) -> dict:
+    def _lower_wave_locked(self, codes, batched) -> tuple:
+        """Group the wave's sequences by body, serve what the cache holds,
+        walk every missing body once at its longest missing count
+        (``_lower``, into one :class:`_WaveRows`), then materialize all of
+        them in one wave pass (:func:`_materialize`).  Returns the
+        programs by sequence index, the rows the loop walked and the rows
+        the wave pass wrote."""
         by_id: dict = {}
         groups: dict = {}
         for c in batched:
@@ -556,9 +697,12 @@ class BatchSimMachine:
                 else:
                     key, nc = (0, ()), 0
                 groups.setdefault(key, []).append((i, nc))
-        progs: dict = {}
         cache = self._lower_cache
         stats = self.lowering_stats
+        acc = _WaveRows()
+        found: list = []        # per group: (body_ck, members, programs)
+        walks: list = []
+        walked: list = []       # per walk: its group's programs
         for (p, body_ck), members in groups.items():
             cuts = sorted({nc for _, nc in members})
             have: dict = {}
@@ -574,18 +718,31 @@ class BatchSimMachine:
                 stats["misses"] += len(missing)
                 rep_i = max(members, key=lambda t: t[1])[0]
                 rep_code = codes[rep_i][:p * missing[-1]]
-                made = self._lower(rep_code, by_id, missing, p)
-                for nc in missing:
-                    have[nc] = made[nc]
-            for nc in cuts:                            # reinsert as newest
-                cache[(body_ck, nc)] = have[nc]
+                walks.append(self._lower(rep_code, by_id, acc, missing, p))
+                walked.append(have)
+            # reinsert as newest; a missing cut's slot is filled after the
+            # wave pass (no later group of this wave probes this body)
+            for nc in cuts:
+                cache[(body_ck, nc)] = have.get(nc)
             if self._lower_max is not None:
                 while len(cache) > self._lower_max:
                     cache.pop(next(iter(cache)))       # oldest entry
                     stats["evictions"] += 1
+            found.append((body_ck, members, have))
+        rows_out = 0
+        if walks:
+            made, rows_out = _materialize(acc, walks)
+            for have, progs_w in zip(walked, made):
+                have.update(progs_w)
+        progs: dict = {}
+        for body_ck, members, have in found:
             for i, nc in members:
                 progs[i] = have[nc]
-        return progs
+            for nc in have:
+                key = (body_ck, nc)
+                if key in cache:        # not evicted: keeps its LRU place
+                    cache[key] = have[nc]
+        return progs, len(acc.issue), rows_out
 
     # ------------------------------------------------------------------
     # recipes: per concrete instruction instance, content-memoized
@@ -699,10 +856,16 @@ class BatchSimMachine:
     # ------------------------------------------------------------------
     # lowering: sequence -> flat tensors (with periodic-steady-state tiling)
     # ------------------------------------------------------------------
-    def _lower(self, code, by_id: dict, cuts=None, period=None) -> dict:
-        """Lower ``code`` (= body * ncopies) and materialize one
-        :class:`_Prog` per requested copy count in ``cuts`` — shorter
-        counts are prefix views of the full tensors."""
+    def _lower(self, code, by_id: dict, acc: _WaveRows, cuts=None,
+               period=None) -> _Walk:
+        """Walk ``code`` (= body * ncopies) μop by μop, appending its native
+        rows to the wave's ``acc``, until the machine state's signature
+        repeats at a copy boundary: from there on the copies are periodic,
+        and the walk only describes them (``_Walk.tile``) for the wave pass
+        (:func:`_materialize`) to unroll.  Builds no arrays.  Producer rows
+        are body-local.  ``cuts`` are the copy counts to return programs
+        for (default: all copies); each gets its row count and final-writer
+        rows."""
         comp = self._comp
         width = comp.issue_width
         penalty = comp.partial_stall_penalty
@@ -721,12 +884,18 @@ class BatchSimMachine:
         ms: set = set()     # mem cells with a store seen
         ec: dict = {}       # elim spec key -> instance count
         ecp: dict = {}      # elim spec key -> period
-        issue_l: list = []
-        mask_l: list = []
-        lat_l: list = []
-        blk_l: list = []
-        vis_l: list = []
-        prods_l: list = []
+        issue_l = acc.issue
+        base = len(issue_l)     # this body's row 0 in the wave's lists
+        issue_a = issue_l.append
+        mask_a = acc.mask.append
+        lat_a = acc.lat.append
+        blk_a = acc.blk.append
+        vis_a = acc.vis.append
+        prod_l = acc.prod
+        prod_a = prod_l.append
+        end_a = acc.prod_end.append
+        dat_a = acc.delta_at.append
+        dval_a = acc.delta.append
         uop_counter = 0
 
         sig_map: dict = {}
@@ -734,7 +903,7 @@ class BatchSimMachine:
         tile = None
 
         def signature():
-            nr = len(issue_l)
+            nr = len(issue_l) - base
             return (uop_counter % width,
                     tuple(sorted((c, nr - r) for c, r in lw.items())),
                     tuple(sorted(wd.items())),
@@ -748,11 +917,10 @@ class BatchSimMachine:
                 c0 = sig_map.get(sig)
                 if c0 is not None:
                     tile = (c0, i)
-                    snaps.append((len(issue_l), uop_counter, dict(lw),
-                                  dict(ml)))
                     break
                 sig_map[sig] = i
-            snaps.append((len(issue_l), uop_counter, dict(lw), dict(ml)))
+            snaps.append((len(issue_l) - base, uop_counter, dict(lw),
+                          dict(ml)))
             for r in body:
                 k = r.kind
                 if k == _K_ZERO_NOUOP:
@@ -775,27 +943,30 @@ class BatchSimMachine:
                          for cell, chk, w in r.snapshot]
                 tmp: dict = {}
                 for pl in r.plans:
-                    row = len(issue_l)
-                    prow = []
+                    row = len(issue_l) - base
                     for kind, a in pl.prods:
                         if kind == _P_SNAP:
-                            prow.append(svals[a])
+                            pp, dd = svals[a]
+                            if dd:
+                                dat_a(len(prod_l))
+                                dval_a(dd)
+                            prod_a(pp)
                         elif kind == _P_TMP:
-                            prow.append((tmp.get(a, -1), 0))
+                            prod_a(tmp.get(a, -1))
                         elif kind == _P_CUR:
-                            prow.append((lw.get(a, -1), 0))
+                            prod_a(lw.get(a, -1))
                         else:   # _P_MEM: reg base + memory value
-                            prow.append((lw.get(a, -1), 0))
-                            prow.append((ml.get(a, -1), 0))
+                            prod_a(lw.get(a, -1))
+                            prod_a(ml.get(a, -1))
+                    end_a(len(prod_l))
                     lat = pl.lat
                     if pl.sf and pl.sf_cell in ms:
                         lat = min(lat, sfl)
-                    issue_l.append((uop_counter + pl.issue_off) // width)
-                    mask_l.append(pl.mask_id)
-                    lat_l.append(lat)
-                    blk_l.append(pl.blk)
-                    vis_l.append(pl.vis)
-                    prods_l.append(prow)
+                    issue_a((uop_counter + pl.issue_off) // width)
+                    mask_a(pl.mask_id)
+                    lat_a(lat)
+                    blk_a(pl.blk)
+                    vis_a(pl.vis)
                     for wk, a, b in pl.writes:
                         if wk == _W_TMP:
                             tmp[a] = row
@@ -807,77 +978,17 @@ class BatchSimMachine:
                             if b is not None:
                                 wd[a] = b
                 uop_counter += r.advance
-        else:
-            snaps.append((len(issue_l), uop_counter, dict(lw), dict(ml)))
+        snaps.append((len(issue_l) - base, uop_counter, dict(lw), dict(ml)))
 
-        # native part -> arrays
-        n_nat = len(issue_l)
-        max_r = max((len(pr) for pr in prods_l), default=0)
-        max_r = max(max_r, 1)
-        issue = np.array(issue_l, np.int64) if n_nat else np.zeros(0, np.int64)
-        mask = np.array(mask_l, np.int64) if n_nat else np.zeros(0, np.int64)
-        lat = np.array(lat_l, np.int64) if n_nat else np.zeros(0, np.int64)
-        blk = np.array(blk_l, np.int64) if n_nat else np.zeros(0, np.int64)
-        vis = np.array(vis_l, np.int64) if n_nat else np.zeros(0, np.int64)
-        prod = np.full((n_nat, max_r), -1, np.int64)
-        delta = np.zeros((n_nat, max_r), np.int64)
-        for j, pr in enumerate(prods_l):
-            for kk, (pp, dd) in enumerate(pr):
-                prod[j, kk] = pp
-                delta[j, kk] = dd
-
-        if tile is None:
-            parts = [(issue, mask, lat, blk, vis, prod, delta)]
-        else:
+        n_nat = len(issue_l) - base
+        desc = None
+        if tile is not None:
             c0, c1 = tile
             s0, u0 = snaps[c0][0], snaps[c0][1]
-            s1, u1 = n_nat, uop_counter
-            d_rows, d_uops = s1 - s0, u1 - u0
+            d_rows, d_uops = n_nat - s0, uop_counter - u0
             assert d_uops % width == 0
-            d_issue = d_uops // width
+            desc = (c0, c1, s0, d_rows, d_uops // width)
             per = c1 - c0
-            rem = ncopies - c1
-            full, left = divmod(rem, per)
-            parts = [(issue, mask, lat, blk, vis, prod, delta)]
-            if full:
-                # all full periods in one broadcast: segment + q * shift
-                q = np.arange(1, full + 1, dtype=np.int64)
-                sl = slice(s0, s1)
-                seg_p = prod[sl]
-                pt = np.where(seg_p[None] >= 0,
-                              seg_p[None] + (q * d_rows)[:, None, None], -1)
-                parts.append((
-                    (issue[sl][None] + (q * d_issue)[:, None]).reshape(-1),
-                    np.tile(mask[sl], full), np.tile(lat[sl], full),
-                    np.tile(blk[sl], full), np.tile(vis[sl], full),
-                    pt.reshape(-1, max_r),
-                    np.tile(delta[sl], (full, 1))))
-            if left:
-                sl = slice(s0, snaps[c0 + left][0])
-                pr = prod[sl]
-                qq = full + 1
-                parts.append((issue[sl] + qq * d_issue, mask[sl], lat[sl],
-                              blk[sl], vis[sl],
-                              np.where(pr >= 0, pr + qq * d_rows, -1),
-                              delta[sl]))
-        if len(parts) > 1:
-            issue = np.concatenate([x[0] for x in parts])
-            mask = np.concatenate([x[1] for x in parts])
-            lat = np.concatenate([x[2] for x in parts])
-            blk = np.concatenate([x[3] for x in parts])
-            vis = np.concatenate([x[4] for x in parts])
-            prod = np.concatenate([x[5] for x in parts])
-            delta = np.concatenate([x[6] for x in parts])
-        # cached tensors are int32: every simulated quantity fits (cycles,
-        # rows, counts < 2^31 - 1 — the device kernels reserve INT32_MAX
-        # as the disallowed-port dispatch sentinel) and run int32 natively
-        issue = issue.astype(np.int32)
-        mask = mask.astype(np.int32)
-        lat = lat.astype(np.int32)
-        blk = blk.astype(np.int32)
-        vis = vis.astype(np.int32)
-        prod = prod.astype(np.int32)
-        delta = delta.astype(np.int32)
 
         def boundary(b):
             """(rows, row shift, reg cells, mem cells) after ``b`` copies."""
@@ -888,16 +999,13 @@ class BatchSimMachine:
             rows_b = s0 + qb * d_rows + (snaps[c0 + rb][0] - s0)
             return rows_b, qb * d_rows, snaps[c0 + rb][2], snaps[c0 + rb][3]
 
-        made: dict = {}
+        made: list = []
         for b in cuts:
             rows_b, sh, lwb, mlb = boundary(b)
             fin = sorted({r + sh for r in lwb.values()}
                          | {r + sh for r in mlb.values()})
-            made[b] = _Prog(rows_b, issue[:rows_b], mask[:rows_b],
-                            lat[:rows_b], blk[:rows_b], vis[:rows_b],
-                            prod[:rows_b], delta[:rows_b],
-                            np.array(fin, np.int64), max_r)
-        return made
+            made.append((b, rows_b, np.array(fin, np.int64)))
+        return _Walk(base, n_nat, desc, boundary(ncopies)[0], made)
 
     # ------------------------------------------------------------------
     # packing: chunk -> bucket tensors (vectorized NumPy scatter)

@@ -4,6 +4,7 @@ bit-identical (cycles AND per-port μop counts) to the scalar oracle
 ground truths — plus the compiled-table and divider-occupancy seams."""
 import random
 
+import numpy as np
 import pytest
 
 from repro.core.batch_sim import BatchSimMachine, _body_period
@@ -395,6 +396,153 @@ def test_lowering_cache_eviction_bound():
     got2 = m.run_batch(codes)
     for a, b in zip(ref, got2):
         assert a.cycles == b.cycles and a.port_uops == b.port_uops
+
+
+# ---------------------------------------------------------------------------
+# the wave pass: steady-state copies unrolled once per wave
+# ---------------------------------------------------------------------------
+
+
+def _random_periodic_body(rng):
+    """A body of a few parts: independent instructions, eliminated-move
+    chains (period 3 on sim_skl), zero idioms feeding a multiply, store ->
+    load round trips through one memory cell (forwarded), and
+    partial-register writes read whole (nonzero producer deltas)."""
+    names = ["ADD_R64_R64", "IMUL_R64_R64", "SHLD_R64_R64_I8", "DIV_R64",
+             "MOV_R64_M64", "ADD_R64_M64", "MOVAPS_X_X", "PADDD_X_X"]
+    reg = lambda: f"R{rng.randrange(6)}"   # noqa: E731
+    body = []
+    for _ in range(rng.randint(1, 3)):
+        kind = rng.choice(["indep", "elim", "zero", "store", "partial"])
+        if kind == "indep":
+            body += independent_seq(TEST_ISA[rng.choice(names)], RegPool(),
+                                    rng.randint(1, 3))
+        elif kind == "elim":
+            k = rng.randint(2, 4)
+            spec = rng.choice(["MOV_R64_R64", "MOVZX_R64_R16"])
+            body += [Instr(spec, {"op1": f"R{(i + 1) % k}",
+                                  "op2": f"R{i % k}"}) for i in range(k)]
+        elif kind == "zero":
+            r = reg()
+            body += [Instr("XOR_R64_R64", {"op1": r, "op2": r}),
+                     Instr("IMUL_R64_R64", {"op1": r, "op2": reg()})]
+        elif kind == "store":
+            r = reg()
+            body += [Instr("MOV_M64_R64", {"mem": "RB0", "op1": r}),
+                     Instr("MOV_R64_M64", {"op1": r, "mem": "RB0"})]
+        else:
+            r = reg()
+            body += [Instr("SETC_R8", {"op1": r}),
+                     Instr("ADD_R64_R64", {"op1": reg(), "op2": r})]
+    return body
+
+
+def _plain_unrolling(m, code):
+    """``code`` lowered as one copy: its period forced to its length, so
+    the walk emits every row and the wave pass tiles nothing."""
+    from repro.core.batch_sim import _materialize, _WaveRows
+    acc = _WaveRows()
+    walk = m._lower(code, {}, acc, [1], len(code))
+    assert walk.tile is None
+    (made,), _ = _materialize(acc, [walk])
+    return made[1]
+
+
+def _assert_same_prog(got, want):
+    assert (got.n_rows, got.max_r) == (want.n_rows, want.max_r)
+    for f in ("issue", "mask", "lat", "blk", "vis", "prod", "delta",
+              "finals"):
+        a, b = getattr(got, f), getattr(want, f)
+        assert a.dtype == b.dtype and a.shape == b.shape, f
+        assert np.array_equal(a, b), f
+
+
+def _steady_cuts(m, base):
+    """Cuts 1, c1 - 1, c1, c1 + 1, 10 and 110 of ``base``, where copy c1
+    is where its walk finds the steady state (none: 1, 10, 110)."""
+    from repro.core.batch_sim import _WaveRows
+    walk = m._lower(base * 110, {}, _WaveRows(), [110], len(base))
+    cuts = {1, 10, 110}
+    if walk.tile is not None:
+        c1 = walk.tile[1]
+        cuts |= {c for c in (c1 - 1, c1, c1 + 1) if 1 <= c <= 110}
+    return sorted(cuts), walk.tile
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_wave_pass_equals_plain_unrolling(seed):
+    from repro.core.batch_sim import _code_period
+    rng = random.Random(seed)
+    m = BatchSimMachine(SIM_SKL, TEST_ISA, min_lanes=1)
+    bases, tiles = [], []
+    for _ in range(6):
+        body = _random_periodic_body(rng)
+        p = _code_period(body * 110)
+        bases.append(body[:p])
+    # a body that never repeats: one copy, nothing tiled
+    names = ["ADD_R64_R64", "IMUL_R64_R64", "ADC_R64_R64", "SHLD_R64_R64_I8"]
+    bases.append([Instr(rng.choice(names), {"op1": f"R{i % 6}",
+                                            "op2": f"R{(i * 7 + 3) % 6}"})
+                  for i in range(rng.randint(7, 13))])
+    codes, owner = [], []
+    for b, base in enumerate(bases):
+        cuts, tile = _steady_cuts(m, base)
+        tiles.append(tile)
+        if b == len(bases) - 1:
+            cuts = [1]
+        for nc in cuts:
+            codes.append(base * nc)
+            owner.append(b)
+    assert any(t is not None and t[3] for t in tiles)   # rows were tiled
+    progs = m._lower_wave(codes, [list(range(len(codes)))])
+    assert m.lowering_stats["hits"] == 0
+    for i, code in enumerate(codes):
+        _assert_same_prog(progs[i], _plain_unrolling(m, code))
+    # one body's cuts are prefixes of one set of rows
+    longest = {owner[i]: i for i in range(len(codes))}
+    for i in range(len(codes)):
+        j = longest[owner[i]]
+        if progs[i].n_rows:
+            assert np.shares_memory(progs[i].issue, progs[j].issue)
+    assert any(progs[i].delta.any() for i in range(len(codes)))
+
+    # a second wave: content-equal copies of half the bodies at their old
+    # cuts (cache hits), at new cuts (misses), and new bodies (misses)
+    fresh = [[Instr(x.spec, dict(x.regs), x.value_hint) for x in base]
+             for base in bases[:3]]
+    codes2 = [f * nc for f in fresh for nc in (1, 10, 110, 37, 64)]
+    codes2 += [b * nc for b in (_random_periodic_body(rng)
+                                for _ in range(3)) for nc in (10, 110)]
+    h0, m0 = m.lowering_stats["hits"], m.lowering_stats["misses"]
+    progs2 = m._lower_wave(codes2, [list(range(len(codes2)))])
+    assert m.lowering_stats["hits"] - h0 == 9
+    assert m.lowering_stats["misses"] - m0 > 6
+    for i, code in enumerate(codes2):
+        _assert_same_prog(progs2[i], _plain_unrolling(m, code))
+
+
+def test_wave_lower_span_counts_rows():
+    """``wave.lower`` carries the rows the loop walked and the rows the
+    wave pass wrote: one unrolling per missing body, at its largest
+    missing cut."""
+    rng = random.Random(11)
+    m = BatchSimMachine(SIM_SKL, TEST_ISA, min_lanes=1)
+    bodies = [_random_periodic_body(rng) for _ in range(4)]
+    codes = [b * nc for b in bodies for nc in (10, 110)]
+    progs, events = _traced(lambda: m._lower_wave(codes, [list(range(8))]))
+    # second wave: bodies 0-1 hit at 10 and 110 and miss at 50; 2-3 hit
+    codes2 = [b * nc for b in bodies for nc in (10, 110)]
+    codes2 += [b * 50 for b in bodies[:2]]
+    progs2, events2 = _traced(lambda: m._lower_wave(
+        codes2, [list(range(len(codes2)))]))
+    (sp,), (sp2,) = ([e["args"] for e in ev if e["name"] == "wave.lower"]
+                     for ev in (events, events2))
+    assert sp["rows_out"] == sum(progs[2 * b + 1].n_rows for b in range(4))
+    assert 0 < sp["rows_native"] <= sp["rows_out"]
+    assert (sp["hits"], sp["misses"]) == (0, 8)
+    assert sp2["rows_out"] == progs2[8].n_rows + progs2[9].n_rows
+    assert 0 < sp2["rows_native"] <= sp2["rows_out"]
+    assert (sp2["hits"], sp2["misses"]) == (8, 2)
 
 
 # ---------------------------------------------------------------------------
