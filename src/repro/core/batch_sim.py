@@ -521,16 +521,19 @@ class BatchSimMachine:
         (``wave.lower`` / ``wave.pack`` / ``wave.kernel`` (host kernels) /
         ``wave.dispatch`` / ``wave.result_wait`` / ``wave.extract``) and
         lock-wait spans (``wave.lock_wait`` / ``wave.dispatch_lock_wait``)
-        measured separately from the work they guard.  ``wave.dispatch``
-        carries the device kernels' ``steps`` (serial scan steps),
+        measured separately from the work they guard.  ``wave.run_batch``
+        carries ``chunks`` (the length chunks that run batched) and
+        ``scalar_lanes`` (lanes of thin chunks run on the scalar oracle);
+        ``wave.dispatch`` carries the device kernels' ``steps`` (serial
+        scan steps),
         ``slots`` (lane-steps, padding included) and ``rows`` (real μop
         rows).  Traced and untraced waves dispatch the same way; the
         kernels' device time is in the profiler trace."""
         with obs.span("wave.run_batch", lanes=len(codes),
-                      backend=self.backend):
-            return self._run_batch(codes, kernel_lock)
+                      backend=self.backend) as span:
+            return self._run_batch(codes, kernel_lock, span)
 
-    def _run_batch(self, codes, kernel_lock=None) -> list:
+    def _run_batch(self, codes, kernel_lock=None, span=obs.NULL_SPAN) -> list:
         codes = [list(c) for c in codes]
         out: list = [None] * len(codes)
         # chunk by similar length so short sequences don't pay for the
@@ -550,6 +553,7 @@ class BatchSimMachine:
             chunks.append(chunk)
         batched = [c for c in chunks if len(c) >= self.min_lanes]
         thin = [i for c in chunks if len(c) < self.min_lanes for i in c]
+        span.set(chunks=len(batched), scalar_lanes=len(thin))
         if thin:
             self._chunk_scalar(thin, codes, out, kernel_lock)
         if not batched:

@@ -78,13 +78,6 @@ class PerfModel:
         return self.instructions[name]
 
 
-def _supported(spec: InstrSpec) -> bool:
-    """Paper §8 limitations: system / serializing / control-flow
-    instructions are not characterized."""
-    return not (spec.system or spec.serializing or spec.control_flow
-                or spec.is_nop)
-
-
 def _instruction_gen(spec: InstrSpec, isa: ISA, blocking: BlockingSet,
                      lat: LatencyPlans, n_ports: int):
     im = InstrModel(spec.name)
@@ -129,7 +122,7 @@ def _characterize_gen(isa: ISA, instr_names, blocking, n_ports: int):
     model.blocking = {"p" + "".join(sorted(pc)): nm
                       for pc, nm in blocking.instrs.items()}
     names = instr_names if instr_names is not None else isa.names()
-    specs = [isa[n] for n in names if _supported(isa[n])]
+    specs = [isa[n] for n in names if isa[n].characterized]
     ims = yield Fork([instruction_plan(spec, isa, blocking, lat,
                                        n_ports=n_ports) for spec in specs])
     for im in ims:

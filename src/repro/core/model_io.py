@@ -23,6 +23,7 @@ from repro.core.latency import LatencyEntry, LatencyResult
 from repro.core.port_usage import PortUsage
 from repro.core.simulator import Counters
 from repro.core.throughput import ThroughputResult
+from repro.obs import tracer as obs
 
 
 def _fmt(x) -> str:
@@ -30,6 +31,16 @@ def _fmt(x) -> str:
 
 
 def to_xml(model: PerfModel, isa=None) -> str:
+    """The model as uops.info-style XML; traced as ``model.export`` with
+    the ``instructions`` exported and the document's ``bytes``."""
+    with obs.span("model.export", uarch=model.uarch,
+                  instructions=len(model.instructions)) as span:
+        text = _to_xml(model, isa)
+        span.set(bytes=len(text))
+    return text
+
+
+def _to_xml(model: PerfModel, isa) -> str:
     root = ET.Element("root")
     arch = ET.SubElement(root, "architecture", name=model.uarch)
     if model.fingerprint:

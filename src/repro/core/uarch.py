@@ -21,7 +21,7 @@ from __future__ import annotations
 import random as _random
 from dataclasses import dataclass, field, replace
 
-from repro.core.isa import ISA, TEST_ISA
+from repro.core.isa import IMM, MEM, generate_isa, variant_unit
 
 # ---------------------------------------------------------------------------
 # TPU v5e roofline constants (per chip)
@@ -225,15 +225,16 @@ def _hsw_behaviors() -> dict[str, InstrBehavior]:
 SIM_HSW = UArch("sim_hsw", tuple("01234567"), 4, _hsw_behaviors())
 
 
+# sim_skl's port sets -> sim_snb's (no port 6 or 7)
+_SNB_REMAP = {P0156: P015, P06: frozenset("05"), P237: P23}
+
+
 def _snb_behaviors() -> dict[str, InstrBehavior]:
     """Sandy-Bridge-like: 6 ports (0,1,5 exec; 2,3 load; 4 store-data)."""
     b = dict(_skl_behaviors())
-    snb_remap = {frozenset("0156"): P015, frozenset("06"): frozenset("05"),
-                 frozenset("237"): P23}
-
     def remap(behavior: InstrBehavior) -> InstrBehavior:
         def fix(u: Uop) -> Uop:
-            return Uop(snb_remap.get(u.ports, u.ports), u.reads, u.writes,
+            return Uop(_SNB_REMAP.get(u.ports, u.ports), u.reads, u.writes,
                        u.latency, u.occupancy)
         return InstrBehavior(
             tuple(fix(u) for u in behavior.uops),
@@ -319,62 +320,159 @@ def _tpu_isa_and_behaviors():
     return isa, b
 
 
+def port_usage_of(behavior: InstrBehavior) -> dict:
+    """The port usage a behavior's μops state: {port set: μops}."""
+    usage: dict = {}
+    for u in behavior.uops:
+        usage[u.ports] = usage.get(u.ports, 0) + 1
+    return usage
+
+
 def make_tpu_sim():
     """(machine-ready uarch, isa, truth) for the TPU-unit port model."""
     isa, behaviors = _tpu_isa_and_behaviors()
     ua = UArch("sim_tpu", TPU_PORTS, 4, behaviors, overhead_cycles=40)
-    truth = {name: {} for name in behaviors}
-    for name, behavior in behaviors.items():
-        for u in behavior.uops:
-            truth[name][u.ports] = truth[name].get(u.ports, 0) + 1
+    truth = {name: port_usage_of(b) for name, b in behaviors.items()}
     return ua, isa, truth
 
 
 # ---------------------------------------------------------------------------
-# randomized ground truths for property-based tests
+# generated ground truths: a paper-scale ISA's hidden tables (and, at small
+# sizes, the randomized ground truths of the property tests)
 # ---------------------------------------------------------------------------
 
+@dataclass(frozen=True)
+class Layout:
+    """A port layout for generated tables: the SIM core whose tables
+    TEST_ISA's variants keep, the map from sim_skl's port sets to the
+    layout's, and the core's rename-stage behaviors for generated moves and
+    zero idioms (as its SIM core has them)."""
+    core: UArch
+    remap: dict
+    elim_period: int          # move elimination; 0: none
+    zero_idiom_no_uop: bool   # zero idioms retire without executing
 
-def random_uarch_and_isa(seed: int, n_instr: int = 6,
-                         ports: tuple[str, ...] = tuple("012345")):
-    """Draw a random hidden ground truth plus an ISA guaranteed to contain a
-    1-μop blocking instruction for every functional-unit port combination
-    (the paper's §5.1.1 assumption). Returns (uarch, isa, truth) where
-    ``truth[name]`` is the port-usage multiset {frozenset: count}."""
-    from repro.core.isa import GPR, InstrSpec, op  # noqa: PLC0415
 
-    rng = _random.Random(seed)
-    n_pc = rng.randint(2, 4)
-    pcs: list[frozenset] = []
-    while len(pcs) < n_pc:
-        k = rng.randint(1, min(3, len(ports)))
-        pc = frozenset(rng.sample(ports, k))
-        if pc not in pcs:
-            # keep combinations either disjoint or strictly nested/overlapping
-            pcs.append(pc)
-    isa = ISA()
-    behaviors: dict[str, InstrBehavior] = {}
-    truth: dict[str, dict[frozenset, int]] = {}
-    # blocking candidates: one 1-uop instr per combination
-    for i, pc in enumerate(pcs):
-        nm = f"BLK{i}"
-        isa.add(InstrSpec(nm, nm, (op("op1", GPR, "w"), op("op2", GPR, "r"))))
-        behaviors[nm] = beh(uop(pc, ("op2",), ("op1",)))
-        truth[nm] = {pc: 1}
-    # random multi-uop instructions over those combinations
-    for i in range(n_instr):
-        nm = f"INS{i}"
-        k = rng.randint(1, 3)
-        usage: dict[frozenset, int] = {}
-        uops = []
-        for j in range(k):
-            pc = rng.choice(pcs)
-            usage[pc] = usage.get(pc, 0) + 1
-            reads = ("op2",) if j == 0 else (f"%{j-1}",)
-            writes = ("op1",) if j == k - 1 else (f"%{j}",)
-            uops.append(uop(pc, reads, writes, rng.randint(1, 4)))
-        isa.add(InstrSpec(nm, nm, (op("op1", GPR, "w"), op("op2", GPR, "r"))))
-        behaviors[nm] = InstrBehavior(tuple(uops))
-        truth[nm] = usage
-    ua = UArch(f"rand{seed}", ports, 6, behaviors, overhead_cycles=50)
-    return ua, isa, truth
+LAYOUTS = {"skl": Layout(SIM_SKL, {}, 3, True),
+           "snb": Layout(SIM_SNB, _SNB_REMAP, 0, False)}
+
+# execution-unit classes: (port set, weight) choices and μop latencies
+_UNITS = {
+    "alu": (((P0156, 6), (P06, 2), (P15, 1), (P1, 1)), (1, 1, 1, 2)),
+    "flagsuse": (((P06, 3), (P0156, 1)), (1, 1, 2)),
+    "shift": (((P06, 4), (P0156, 1)), (1, 1, 2)),
+    "mul": (((P1, 4), (P15, 1), (P5, 1)), (3, 3, 4)),
+    "bitcnt": (((P1, 3), (P0156, 1)), (3, 3, 1)),
+    "lea": (((P15, 3), (P1, 1)), (1, 1, 3)),
+    "move": (((P0156, 1),), (1,)),
+    "samereg": (((P1, 2), (P06, 1)), (3, 3, 4)),
+    "vint": (((P015, 3), (P01, 1), (P5, 1), (P0, 1), (P15, 1)), (1, 1, 2)),
+    "vmul": (((P01, 3), (P0, 1)), (5, 5, 4)),
+    "vshuf": (((P5, 4), (P15, 1)), (1, 1, 3)),
+    "vshift": (((P01, 2), (P5, 1), (P0, 1)), (1, 1, 2)),
+    "vfp": (((P01, 3), (P015, 1), (P5, 1)), (4, 4, 3)),
+    "vmove": (((P015, 1),), (1,)),
+    "cross": (((P5, 2), (P0, 1), (P015, 1)), (1, 2, 3)),
+    "aes": (((P0, 1),), (4,)),
+    "excluded": (((P0156, 3), (P06, 1)), (1, 2, 5)),
+}
+_DIVIDERS = {"div": ((20, 40), (4, 10), (5, 15)),     # latency, occupancy,
+             "vdiv": ((11, 18), (3, 6), (2, 4))}      # high-value extra
+_MOVES = ("move", "vmove")   # a load or store is the whole instruction
+
+
+def _pick(rng, choices):
+    return rng.choices([c for c, _ in choices],
+                       weights=[w for _, w in choices])[0]
+
+
+def _draw_behavior(rng, spec, unit: str, layout: Layout) -> InstrBehavior:
+    """1–4 μops for one generated variant: a load first where it reads
+    memory, then a chain of execution μops (the first reads the register
+    sources, each next one the previous result, the last writes the
+    destinations), then store-address and store-data where it writes
+    memory.  Latencies 1–5; only dividers run longer, on a port they
+    occupy for several cycles."""
+    if spec.is_nop:
+        return beh()
+    mem = [o for o in spec.operands if o.otype == MEM]
+    load = any(o.read for o in mem)
+    store = any(o.written for o in mem)
+    srcs = tuple(o.name for o in spec.operands
+                 if o.read and o.otype not in (MEM, IMM))
+    dsts = tuple(o.name for o in spec.operands
+                 if o.written and o.otype != MEM)
+    room = 4 - load - 2 * store
+    n = 0 if unit in _MOVES and (load or store) else min(
+        room, 1 if unit in _MOVES or spec.may_eliminate
+        else rng.choices((1, 2, 3), weights=(7, 2, 1))[0])
+    uops_: list = []
+    val = srcs
+    if load:
+        out = ("%m",) if n or store else dsts
+        uops_.append(uop(P23, ("mem",), out, 5))
+        val = ("%m",) + srcs
+    choices, lats = _UNITS.get(unit, _UNITS["alu"])   # dividers: later μops
+    div = _DIVIDERS.get(unit)
+    for j in range(n):
+        last = j == n - 1
+        writes = ((dsts + ("%v",)) if store else dsts) if last else (f"%{j}",)
+        if div is not None and j == 0:
+            (l0, l1), (o0, o1), _ = div
+            uops_.append(uop(P0, val, writes, rng.randint(l0, l1),
+                             rng.randint(o0, o1)))
+        else:
+            uops_.append(uop(_pick(rng, choices), val, writes,
+                             rng.choice(lats)))
+        val = (f"%{j}",)
+    if store:
+        data = ("%v",) if n else val
+        uops_ += [uop(P237, ("mem",), ("%a",)),
+                  uop(P4, data + ("%a",), ("mem",))]
+
+    def laid_out(us):
+        return tuple(Uop(layout.remap.get(u.ports, u.ports), u.reads,
+                         u.writes, u.latency, u.occupancy) for u in us)
+
+    kw = {}
+    if div is not None:
+        kw["divider_extra"] = rng.randint(*div[2])
+    if spec.may_eliminate:
+        kw["elim_period"] = layout.elim_period
+    if spec.zero_idiom:
+        kw["dep_breaking_same_reg"] = True
+        # compare-greater idioms break the dependency but still execute
+        kw["zero_uop_same_reg"] = layout.zero_idiom_no_uop and \
+            "PCMPGT" not in spec.mnemonic
+    if unit == "samereg":          # §7.3.2: a faster same-register path
+        fast = uops_[:]
+        i = next(i for i, u in enumerate(fast) if u.ports != P23)
+        fast[i] = Uop(fast[i].ports, fast[i].reads, fast[i].writes, 1,
+                      fast[i].occupancy)
+        kw["same_reg"] = InstrBehavior(laid_out(fast))
+    return InstrBehavior(laid_out(uops_), **kw)
+
+
+def generate_isa_and_uarch(seed: int, n_variants: int, layout: str = "skl"):
+    """A seeded paper-scale ISA and the hidden tables of one core with the
+    ``layout``'s ports (``"skl"``: 01234567, ``"snb"``: 012345).
+
+    The ISA is :func:`repro.core.isa.generate_isa`: all of TEST_ISA, whose
+    variants keep the layout's SIM core's tables (so the latency chains and
+    blocking candidates exist by name), plus drawn variants, each with
+    1–4 μops on sim_skl's port sets (remapped for ``"snb"``).  Every port
+    set has a 1-μop blocking candidate in the BASE/SSE and in the
+    BASE/AVX set (§5.1.1).  The ISA depends on ``seed`` alone, the tables
+    on ``seed`` and ``layout``.  Returns ``(isa, uarch)``."""
+    if layout not in LAYOUTS:
+        raise ValueError(f"unknown layout {layout!r}; one of {list(LAYOUTS)}")
+    lay = LAYOUTS[layout]
+    isa = generate_isa(seed, n_variants)
+    rng = _random.Random(f"generate_uarch/{seed}")
+    behaviors = dict(lay.core.behaviors)
+    for spec in isa:
+        if spec.name not in behaviors:
+            behaviors[spec.name] = _draw_behavior(rng, spec,
+                                                  variant_unit(spec), lay)
+    ua = lay.core.replace(name=f"gen_{layout}", behaviors=behaviors)
+    return isa, ua

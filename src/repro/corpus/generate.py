@@ -71,16 +71,11 @@ class CorpusSpec:
                 "family_mix": {f: w for f, w in self.family_mix}}
 
 
-def _supported(spec) -> bool:
-    return not (spec.system or spec.serializing or spec.control_flow
-                or spec.is_nop)
-
-
 def variant_pool(uarch_name: str, isa: ISA) -> list[str]:
     """Variant names this uarch implements and the tool characterizes."""
     ua = SIM_UARCHES[uarch_name]
     return sorted(n for n in isa.names()
-                  if n in ua.behaviors and _supported(isa[n]))
+                  if n in ua.behaviors and isa[n].characterized)
 
 
 def _regs_for(spec, rng: random.Random, pools) -> dict[str, str]:

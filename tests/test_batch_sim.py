@@ -9,12 +9,13 @@ import pytest
 
 from repro.core.batch_sim import BatchSimMachine, _body_period
 from repro.core.engine import Campaign, as_engine
-from repro.core.isa import TEST_ISA
+from repro.core.isa import GPR, MEM, TEST_ISA, VEC
 from repro.core.machine import RegPool, independent_seq
 from repro.core.simulator import Instr, SimMachine
-from repro.core.uarch import (SIM_SKL, SIM_UARCHES, UArch, beh, make_tpu_sim,
-                              random_uarch_and_isa, uop)
+from repro.core.uarch import (SIM_SKL, SIM_UARCHES, UArch, beh,
+                              generate_isa_and_uarch, make_tpu_sim, uop)
 from repro.core.uarch_compile import UopTableIndex, compile_uarch
+from random_truth import random_uarch_and_isa
 
 try:
     import hypothesis  # noqa: F401
@@ -99,6 +100,37 @@ def _random_wave(ua_seed, wave_seed, n_codes=8):
 def test_batch_identical_on_random_ground_truths(seed):
     """Seeded fallback for the hypothesis property below — always runs."""
     ua, isa, codes = _random_wave(seed, seed + 100)
+    assert_wave_matches(ua, isa, codes)
+
+
+def _generated_wave(seed, n_codes=8):
+    """Random bodies over a small generated ISA (either layout): any of
+    its variants, registers drawn from small pools per operand type (so
+    operands often coincide), low or high divider values."""
+    layout = ("skl", "snb")[seed % 2]
+    isa, ua = generate_isa_and_uarch(seed, len(TEST_ISA) + 13, layout)
+    rng = random.Random(seed + 100)
+    names = isa.names()
+    pools = {GPR: "R", VEC: "X", MEM: "RB"}
+
+    def instr():
+        spec = isa[rng.choice(names)]
+        regs = {o.name: f"{pools[o.otype]}{rng.randint(0, 3)}"
+                for o in spec.explicit_operands if o.otype in pools}
+        return Instr(spec.name, regs, rng.choice(("low", "high")))
+
+    codes = []
+    for _ in range(n_codes):
+        body = [instr() for _ in range(rng.randint(1, 5))]
+        codes.append(body * rng.choice([1, 3, 10, 37, 110]))
+    return ua, isa, codes
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_batch_identical_on_generated_ground_truths(seed):
+    """Generated ISAs bring memory operands, vector registers, dividers,
+    zero idioms and eliminable moves into the wave."""
+    ua, isa, codes = _generated_wave(seed)
     assert_wave_matches(ua, isa, codes)
 
 
@@ -1095,3 +1127,27 @@ def test_device_kernel_module_names():
     bound = lower_port_bound(8, 3, 4).compile().as_text()
     assert wave.startswith("HloModule jit_run,")
     assert bound.startswith("HloModule jit_port_bound_kernel,")
+
+
+def test_run_batch_span_counts_chunks_and_scalar_lanes():
+    """``wave.run_batch`` carries the length chunks that run batched and
+    the lanes of thin chunks sent to the scalar oracle."""
+    body = independent_seq(TEST_ISA["IMUL_R64_R64"], RegPool(), 4)
+    short = [Instr("ADD_R64_R64", {"op1": "R0", "op2": "R1"})]
+    codes = [body * 110] * 5 + [short] * 2     # 2 short lanes: a thin chunk
+    m = BatchSimMachine(SIM_SKL, TEST_ISA, min_lanes=4)
+    out, events = _traced(lambda: m.run_batch(codes))
+    (sp,) = [e["args"] for e in events if e["name"] == "wave.run_batch"]
+    assert (sp["lanes"], sp["chunks"], sp["scalar_lanes"]) == (7, 1, 2)
+    scalar = SimMachine(SIM_SKL, TEST_ISA)
+    assert [c.cycles for c in out] == [scalar.run(c).cycles for c in codes]
+
+
+def test_export_span_counts_instructions_and_bytes():
+    from repro.core import model_io
+    from repro.core.characterize import characterize
+    model = characterize(SimMachine(SIM_SKL, TEST_ISA), TEST_ISA,
+                         ["ADD_R64_R64", "MUL_R64"])
+    xml, events = _traced(lambda: model_io.to_xml(model, TEST_ISA))
+    (sp,) = [e["args"] for e in events if e["name"] == "model.export"]
+    assert sp == {"uarch": "sim_skl", "instructions": 2, "bytes": len(xml)}

@@ -15,11 +15,13 @@ from repro.core.characterize import characterize
 from repro.core.engine import (Campaign, Experiment, MeasurementEngine,
                                as_engine, canonical_code,
                                machine_fingerprint)
-from repro.core.isa import TEST_ISA
+from repro.core.isa import ANCHORS, TEST_ISA
 from repro.core.machine import RegPool, independent_seq, measure
 from repro.core.port_usage import infer_port_usage
 from repro.core.simulator import Instr, SimMachine
-from repro.core.uarch import SIM_UARCHES, random_uarch_and_isa
+from repro.core.uarch import (SIM_UARCHES, generate_isa_and_uarch,
+                              port_usage_of)
+from random_truth import random_uarch_and_isa
 
 SUBSET = ["ADD_R64_R64", "ADC_R64_R64", "MOVQ2DQ_X_X", "MUL_R64",
           "SHLD_R64_R64_I8", "MOV_M64_R64", "DIV_R64"]
@@ -133,6 +135,23 @@ def test_port_usage_cache_invariant_on_random_ground_truths(seed):
                                       blocking, max_latency=4).usage
         without = infer_port_usage(MeasurementEngine(m, enabled=False), isa,
                                    name, blocking, max_latency=4).usage
+        assert with_cache == without == truth[name]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 1234])
+def test_port_usage_cache_invariant_on_generated_ground_truths(seed):
+    isa, ua = generate_isa_and_uarch(seed, len(TEST_ISA) + len(ANCHORS) + 4)
+    m = SimMachine(ua, isa)
+    blocking = find_blocking_instructions(as_engine(m), isa)
+    truth = {s.name: port_usage_of(ua.behaviors[s.name])
+             for s in list(isa)[len(TEST_ISA):]
+             if s.characterized and not s.may_eliminate}
+    for name in truth:
+        lat = sum(u.latency for u in ua.behaviors[name].uops)
+        with_cache = infer_port_usage(MeasurementEngine(m), isa, name,
+                                      blocking, max_latency=lat).usage
+        without = infer_port_usage(MeasurementEngine(m, enabled=False), isa,
+                                   name, blocking, max_latency=lat).usage
         assert with_cache == without == truth[name]
 
 

@@ -8,13 +8,15 @@ from hypothesis import HealthCheck, given, settings
 from hypothesis import strategies as st
 
 from repro.core.blocking import find_blocking_instructions
-from repro.core.isa import GPR, ISA, InstrSpec, op
+from repro.core.isa import ANCHORS, GPR, ISA, TEST_ISA, InstrSpec, op
 from repro.core.latency import LatencyAnalyzer
 from repro.core.lp import _bisect_flow, throughput_lp
 from repro.core.port_usage import infer_port_usage
 from repro.core.simulator import SimMachine
 from repro.core.throughput import measure_throughput
-from repro.core.uarch import InstrBehavior, UArch, random_uarch_and_isa, uop
+from repro.core.uarch import (LAYOUTS, InstrBehavior, UArch,
+                              generate_isa_and_uarch, port_usage_of, uop)
+from random_truth import random_uarch_and_isa
 
 SET = settings(max_examples=20, deadline=None,
                suppress_health_check=[HealthCheck.too_slow])
@@ -31,6 +33,39 @@ def test_algorithm1_recovers_random_port_usage(seed):
     for name, expect in truth.items():
         got = infer_port_usage(m, isa, name, blocking, max_latency=4).usage
         assert got == expect, (name, got, expect)
+
+
+def _drawn(seed: int, n: int, layout: str = "skl"):
+    """A generated ISA with ``n`` drawn variants beyond TEST_ISA and its
+    anchors; returns (uarch, isa, the drawn characterized variants)."""
+    isa, ua = generate_isa_and_uarch(seed, len(TEST_ISA) + len(ANCHORS) + n,
+                                     layout)
+    drawn = [s for s in list(isa)[len(TEST_ISA):] if s.characterized]
+    return ua, isa, drawn
+
+
+def _eliminated(spec, ua) -> bool:
+    return spec.may_eliminate and ua.behaviors[spec.name].elim_period > 0
+
+
+@given(seed=st.integers(0, 10_000), layout=st.sampled_from(sorted(LAYOUTS)))
+@SET
+def test_algorithm1_recovers_generated_port_usage(seed, layout):
+    """The same on the program's generated ISAs (which keep a 1-μop
+    blocking instruction for every port set they use): Algorithm 1
+    recovers the exact port-usage multiset (none for an eliminated move);
+    blockRep is sized from the sum of the μop latencies, an upper bound
+    on every operand pair's."""
+    ua, isa, drawn = _drawn(seed, 4, layout)
+    m = SimMachine(ua, isa)
+    blocking = find_blocking_instructions(m, isa)
+    for spec in drawn:
+        b = ua.behaviors[spec.name]
+        expect = {} if _eliminated(spec, ua) else port_usage_of(b)
+        got = infer_port_usage(m, isa, spec.name, blocking,
+                               max_latency=sum(u.latency for u in b.uops)
+                               ).usage
+        assert got == expect, (spec.name, got, expect)
 
 
 def _chain_isa(seed: int, lats):
@@ -107,6 +142,22 @@ def test_measured_throughput_ge_lp(seed):
 
 @given(seed=st.integers(0, 3000))
 @SET
+def test_measured_throughput_ge_lp_on_generated_isa(seed):
+    """The same on the program's generated ISAs, for every instruction
+    whose μops all execute (an eliminated move runs fewer)."""
+    ua, isa, drawn = _drawn(seed, 3)
+    m = SimMachine(ua, isa)
+    for spec in drawn:
+        if _eliminated(spec, ua):
+            continue
+        usage = port_usage_of(ua.behaviors[spec.name])
+        meas = measure_throughput(m, isa, spec.name).measured
+        lp = throughput_lp(usage)
+        assert meas >= lp - 0.05, (spec.name, meas, lp)
+
+
+@given(seed=st.integers(0, 3000))
+@SET
 def test_simulator_port_counts_conserve_uops(seed):
     """Per-port counters sum to the total μop count of the program."""
     ua, isa, truth = random_uarch_and_isa(seed, n_instr=4)
@@ -117,6 +168,25 @@ def test_simulator_port_counts_conserve_uops(seed):
     for name, usage in truth.items():
         seq = independent_seq(isa[name], pool, 5)
         c = m.run(seq)
+        assert c.total_uops == 5 * sum(usage.values())
+
+
+@given(seed=st.integers(0, 3000))
+@SET
+def test_simulator_port_counts_conserve_uops_on_generated_isa(seed):
+    """The same on the program's generated ISAs (loads, stores and
+    multi-μop chains included), for every instruction whose μops all
+    execute."""
+    ua, isa, drawn = _drawn(seed, 4)
+    m = SimMachine(ua, isa)
+    from repro.core.machine import RegPool, independent_seq
+
+    pool = RegPool()
+    for spec in drawn:
+        if _eliminated(spec, ua):
+            continue
+        usage = port_usage_of(ua.behaviors[spec.name])
+        c = m.run(independent_seq(spec, pool, 5))
         assert c.total_uops == 5 * sum(usage.values())
 
 
