@@ -3,7 +3,8 @@
 For every supported instruction variant:
   1. per-operand-pair latencies (§5.2)  — also provides maxLatency for 2.,
   2. port usage via Algorithm 1 (§5.1)  — needs the blocking instructions,
-  3. measured throughput (§5.3.1) and LP throughput from port usage (§5.3.2).
+  3. measured throughput (§5.3.1) and computed throughput from port usage
+     (§5.3.2's LP bound, by its closed form).
 
 The result (:class:`PerfModel`) is the machine-readable artifact (§6.4)
 consumed by the predictor and exported to XML/JSON by ``model_io``.
@@ -38,6 +39,7 @@ from repro.core.blocking import BlockingSet, blocking_plan
 from repro.core.engine import as_engine, machine_fingerprint
 from repro.core.isa import ISA, InstrSpec
 from repro.core.latency import LatencyPlans, LatencyResult
+from repro.core.lp import closed_form_applies
 from repro.core.machine import total_uops_plan
 from repro.core.plan import (Fork, MeasurementPlan, SchedulerStats,
                              WaveScheduler, run_plan)
@@ -224,7 +226,13 @@ def _run_characterize(engine, isa, instr_names, blocking, scheduler,
     hits = (model.engine_stats["cache_hits"]
             + model.engine_stats["dedup_hits"])
     model.engine_stats["hit_rate"] = round(hits / max(1, req), 4)
+    # how the computed throughputs were bounded: closed form or LP fallback
+    bounded = [closed_form_applies(im.port_usage.usage)
+               for im in model.instructions.values()
+               if im.throughput.computed_from_ports is not None]
     span.set(instructions=len(model.instructions),
              waves=model.wave_stats.get("waves", 0),
-             hit_rate=model.engine_stats["hit_rate"])
+             hit_rate=model.engine_stats["hit_rate"],
+             tp_closed=sum(bounded),
+             tp_lp_fallbacks=len(bounded) - sum(bounded))
     return model

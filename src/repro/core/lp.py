@@ -8,7 +8,7 @@ Linearized with z ≥ Σ f(p, pc) per port. Solved with scipy's HiGHS; a pure
 bisection + max-flow feasibility fallback (networkx) covers environments
 without scipy and doubles as an independent check in tests.
 
-For prediction serving there is also a *closed form*: by max-flow/min-cut
+There is also a *closed form*: by max-flow/min-cut
 (Hall's condition on the bipartite pc→port graph), a makespan z is feasible
 iff for every port set S, the μops that can only run on S fit: demand(S) ≤
 z·|S|, where demand(S) = Σ{μ(pc) : pc ⊆ S}. The binding S can always be
@@ -18,9 +18,11 @@ taken as a union of the port combinations present, so
 
 which is exact, needs no solver, and vectorizes across many blocks as one
 matrix pass (see service/batch_predictor.py). ``port_bound_from_usage`` is
-the shared entry point: closed form while the union closure stays small,
-LP fallback beyond that — both the single-block reference predictor and the
-batched service path route through it, so their numbers are identical.
+the shared entry point: closed form up to ``CUT_COMBO_CAP`` distinct port
+combinations, LP beyond that. The campaign's computed throughput
+(``tp_ports``, ``core/throughput.computed_throughput``), the single-block
+reference predictor and the batched service path all route through it, so
+their numbers are identical.
 """
 from __future__ import annotations
 
@@ -90,15 +92,22 @@ def cut_bound(usage: dict, candidates=None) -> float:
     return best
 
 
+def closed_form_applies(usage: dict, combo_cap: int = CUT_COMBO_CAP
+                        ) -> bool:
+    """Whether :func:`port_bound_from_usage` bounds ``usage`` by the closed
+    form (at most ``combo_cap`` distinct combinations) rather than the LP."""
+    return sum(1 for n in usage.values() if n > 0) <= combo_cap
+
+
 def port_bound_from_usage(usage: dict, combo_cap: int = CUT_COMBO_CAP
                           ) -> float:
-    """Port-pressure bound shared by the reference and batched predictors:
-    the closed-form cut bound when few distinct combinations are involved
-    (the common case), the LP otherwise."""
-    distinct = [pc for pc, n in usage.items() if n > 0]
-    if not distinct:
+    """Port-pressure bound shared by the campaign's computed throughput and
+    the reference and batched predictors: the closed-form cut bound when
+    few distinct combinations are involved (the common case), the LP
+    otherwise."""
+    if not any(n > 0 for n in usage.values()):
         return 0.0
-    if len(distinct) > combo_cap:
+    if not closed_form_applies(usage, combo_cap):
         # canonical variable order: the LP result must not depend on dict
         # insertion order (in-memory vs artifact-loaded models)
         return throughput_lp(dict(sorted(usage.items(),

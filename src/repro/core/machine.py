@@ -29,7 +29,6 @@ running them entirely under the lock.
 """
 from __future__ import annotations
 
-import itertools
 from dataclasses import dataclass
 
 from repro.core.engine import (N_LARGE, N_SMALL, Experiment, as_engine,
@@ -58,27 +57,33 @@ class RegPool:
     n_mem: int = 8
 
     def __post_init__(self):
-        self._iters = {}
+        self._names_of = {}  # otype -> tuple of names, built on first use
+        self._cursor = {}    # otype -> index of the next name to hand out
 
-    def _names(self, otype: str):
+    def _names(self, otype: str) -> tuple:
         if otype == GPR:
-            return [f"R{i}" for i in range(self.n_gpr)]
+            return tuple(f"R{i}" for i in range(self.n_gpr))
         if otype == VEC:
-            return [f"X{i}" for i in range(self.n_vec)]
+            return tuple(f"X{i}" for i in range(self.n_vec))
         if otype == MEM:
-            return [f"RB{i}" for i in range(self.n_mem)]  # base registers
+            return tuple(f"RB{i}" for i in range(self.n_mem))  # base regs
         if otype == FLAGS:
-            return ["FLAGS"]
-        return ["IMM"]
+            return ("FLAGS",)
+        return ("IMM",)
 
     def take(self, otype: str, avoid: set = frozenset()) -> str:
-        it = self._iters.get(otype)
-        if it is None:
-            it = self._iters[otype] = itertools.cycle(self._names(otype))
-        for _ in range(4 * len(self._names(otype))):
-            r = next(it)
-            if r not in avoid:
-                return r
+        """The next name of ``otype``'s round robin not in ``avoid``; the
+        cursor moves just past it. Raises if every name is avoided."""
+        names = self._names_of.get(otype)
+        if names is None:
+            names = self._names_of[otype] = self._names(otype)
+        start = self._cursor.get(otype, 0)
+        n = len(names)
+        for k in range(n):
+            i = (start + k) % n
+            if names[i] not in avoid:
+                self._cursor[otype] = (i + 1) % n
+                return names[i]
         raise RuntimeError(f"register pool exhausted for {otype}")
 
 
@@ -88,8 +93,8 @@ def fresh_instance(spec: InstrSpec, pool: RegPool,
     from ``avoid`` and, via round-robin, from recent instances)."""
     regs = {}
     used = set(avoid)
-    for o in spec.explicit_operands:
-        if o.otype == IMM:
+    for o in spec.operands:
+        if o.implicit or o.otype == IMM:
             continue
         r = pool.take(o.otype, used)
         regs[o.name] = r

@@ -10,10 +10,12 @@ Two notions are produced for every instruction:
   dependency-breaking instructions (which consume execution resources
   themselves, so it does not always help — both variants are recorded).
 
-* ``computed`` (Intel, Def. 1): from the inferred port usage via the LP of
-  §5.3.2 — the minimal achievable maximum port load. Not valid for divider
-  instructions (the divider is not fully pipelined), which keep the measured
-  value annotated instead.
+* ``computed`` (Intel, Def. 1): from the inferred port usage, the minimal
+  achievable maximum port load of §5.3.2's LP. It is evaluated by the LP's
+  exact closed form (``core/lp.port_bound_from_usage``, the predictors' own
+  bound), with the LP itself only above ``CUT_COMBO_CAP`` distinct port
+  combinations. Not valid for divider instructions (the divider is not fully
+  pipelined), which keep the measured value annotated instead.
 
 All sequence lengths (and the divider high-operand variants) are independent
 experiments, requested as one wave by a single-yield measurement plan
@@ -27,7 +29,7 @@ from dataclasses import dataclass, field
 
 from repro.core.engine import Experiment, as_engine
 from repro.core.isa import FLAGS, ISA, InstrSpec
-from repro.core.lp import throughput_lp
+from repro.core.lp import port_bound_from_usage
 from repro.core.machine import (RegPool, flags_breaker, independent_experiment,
                                 independent_seq)
 from repro.core.plan import MeasurementPlan, run_plan
@@ -109,4 +111,4 @@ def computed_throughput(usage: PortUsage, spec: InstrSpec) -> float | None:
     """Intel-definition throughput from port usage (invalid for dividers)."""
     if spec.uses_divider or not usage.usage:
         return None
-    return throughput_lp(usage.usage)
+    return port_bound_from_usage(usage.usage)

@@ -4,8 +4,11 @@ import random
 import pytest
 
 from repro.core.isa import TEST_ISA
-from repro.core.lp import (_bisect_flow, cut_bound, port_bound_from_usage,
-                           throughput_lp, union_closure)
+from repro.core.lp import (CUT_COMBO_CAP, _bisect_flow, closed_form_applies,
+                           cut_bound, port_bound_from_usage, throughput_lp,
+                           union_closure)
+from repro.core.model_io import _fmt, to_xml
+from repro.core.port_usage import PortUsage
 from repro.core.throughput import computed_throughput, measure_throughput
 
 
@@ -101,3 +104,90 @@ def test_intel_vs_fog_definitions_diverge(skl_model):
     im = skl_model["CMC"]
     assert im.throughput.computed_from_ports == pytest.approx(0.25, abs=0.01)
     assert im.throughput.measured == pytest.approx(1.0, abs=0.05)
+
+
+def _characterized(which):
+    """A whole ISA characterized on one machine, with the ``characterize``
+    span's attributes."""
+    import xml.etree.ElementTree as ET
+
+    from repro.core.characterize import characterize
+    from repro.core.simulator import SimMachine
+    from repro.core.uarch import SIM_SKL, generate_isa_and_uarch
+    from repro.obs.tracer import Tracer, set_tracer
+
+    if which == "test_isa":
+        isa, ua = TEST_ISA, SIM_SKL
+    else:
+        isa, ua = generate_isa_and_uarch(1810, 128, "skl")
+    tr = Tracer(enabled=True)
+    prev = set_tracer(tr)
+    try:
+        model = characterize(SimMachine(ua, isa), isa)
+    finally:
+        set_tracer(prev)
+    [span] = [e["args"] for e in tr.events() if e["name"] == "characterize"]
+    exported = {el.get("name"): m.get("tp_ports")
+                for el in ET.fromstring(to_xml(model, isa)).iter(
+                    "instruction")
+                for m in el.iter("measurement")
+                if m.get("tp_ports") is not None}
+    return isa, model, span, exported
+
+
+@pytest.mark.parametrize("which", ["test_isa", "generated_128"])
+def test_campaign_port_bound_is_the_closed_form(which):
+    """The campaign's computed throughput is the predictors' closed form,
+    equal to the LP within 1e-9 and exported exactly as the LP's value
+    formats; the span counts every bound as closed-form."""
+    isa, model, span, exported = _characterized(which)
+    bounded = 0
+    for name, im in model.instructions.items():
+        usage = im.port_usage.usage
+        tp = im.throughput.computed_from_ports
+        if isa[name].uses_divider or not usage:
+            assert tp is None, name
+            continue
+        bounded += 1
+        assert tp == port_bound_from_usage(usage), name
+        lp = throughput_lp(usage)
+        assert tp == pytest.approx(lp, abs=1e-9), name
+        assert exported[name] == _fmt(lp), name
+    assert bounded > 0 and len(exported) == bounded
+    assert span["tp_closed"] == bounded
+    assert span["tp_lp_fallbacks"] == 0
+
+
+def test_port_bound_above_combo_cap_uses_the_lp(monkeypatch):
+    """More than ``CUT_COMBO_CAP`` distinct combinations: the bound comes
+    from the LP, over the canonical (sorted) variable order."""
+    import repro.core.lp as lp_mod
+
+    rng = random.Random(16)
+    ports = "0123456789AB"
+    combos = set()
+    while len(combos) < CUT_COMBO_CAP + 1:
+        combos.add(frozenset(rng.sample(ports, rng.randint(1, 3))))
+    usage = {pc: rng.randint(1, 4) for pc in combos}
+    assert not closed_form_applies(usage)
+    calls = []
+    real_lp = lp_mod.throughput_lp
+
+    def spy(u, ports=None):
+        calls.append(list(u))
+        return real_lp(u, ports)
+
+    monkeypatch.setattr(lp_mod, "throughput_lp", spy)
+    spec = TEST_ISA["ADD_R64_R64"]
+    tp = computed_throughput(PortUsage(usage=usage), spec)
+    assert calls == [sorted(usage, key=sorted)]
+    assert tp == real_lp(dict(sorted(usage.items(),
+                                     key=lambda kv: sorted(kv[0]))))
+    assert tp == pytest.approx(cut_bound(usage), abs=1e-9)
+    # at the cap itself the closed form still applies: no LP call
+    calls.clear()
+    capped = dict(list(usage.items())[:CUT_COMBO_CAP])
+    assert closed_form_applies(capped)
+    assert computed_throughput(PortUsage(usage=capped), spec) == \
+        cut_bound(capped)
+    assert calls == []
