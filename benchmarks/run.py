@@ -20,9 +20,9 @@ a human-readable table per benchmark. Paper mapping:
                             vs NumPy vs jax batched backend, wave sweep +
                             thin-chunk scalar-crossover sweep (min_lanes)
   bench_backend_matrix      device-resident wave execution: numpy vs jax
-                            (blocked scan) vs pallas (interpret off-TPU)
-                            across wave widths, cold vs warm lowering
-                            cache, with the kernel recompile probe
+                            (blocked scan) across wave widths, cold vs
+                            warm lowering cache, with the kernel
+                            recompile probe
   bench_trace_overhead      observability tax: numpy wave sweep with
                             repro.obs tracing off vs on, plus the analytic
                             disabled-overhead bound the CI <2% gate asserts
@@ -366,7 +366,7 @@ def bench_kernel_contention():
     def target():
         return ref.reference_attention(q, q, q, causal=True)
 
-    # CPU stand-ins for the blockers (the Pallas blockers run on TPU)
+    # CPU stand-ins for the blockers (kernels/microbench.py's run on TPU)
     a = jnp.ones((128, 128), jnp.float32)
     v = jnp.ones((1 << 14,), jnp.float32)
     blockers = {
@@ -506,9 +506,9 @@ BACKEND_MATRIX_STATS: dict = {}
 
 
 def bench_backend_matrix(smoke: bool = False):
-    """Device-resident wave execution: numpy vs jax (blocked AOT scan) vs
-    pallas (interpret mode off-TPU) across wave widths, with a cold and a
-    warm lowering-cache pass per cell.  Kernel compilation is shared
+    """Device-resident wave execution: numpy vs jax (blocked AOT scan)
+    across wave widths, with a cold and a warm lowering-cache pass per
+    cell.  Kernel compilation is shared
     module-wide per shape bucket, so the cold pass measures lowering +
     packing + execution (one pre-pass per backend absorbs compiles and
     feeds the recompile probe: a fresh machine over the same shapes must
@@ -525,7 +525,7 @@ def bench_backend_matrix(smoke: bool = False):
 
     try:
         import jax  # noqa: F401
-        backends = ("numpy", "jax", "pallas")
+        backends = ("numpy", "jax")
     except ImportError:
         backends = ("numpy",)
 
@@ -535,8 +535,8 @@ def bench_backend_matrix(smoke: bool = False):
     scalar = SimMachine(SIM_SKL, TEST_ISA)
     waves = (8, 32) if smoke else (32, 128, 512)
     rows = []
-    print("\n== backend matrix: numpy / jax scan / pallas, cold+warm "
-          "lowering cache ==")
+    print("\n== backend matrix: numpy / jax scan, cold+warm lowering "
+          "cache ==")
     print(f"{'wave':>6s} {'backend':>8s} {'cold_s':>8s} {'warm_s':>8s} "
           f"{'vs_numpy':>9s} {'compiles':>9s}")
     for wave in waves:

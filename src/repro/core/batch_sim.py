@@ -6,7 +6,7 @@ module executes a *wave* of experiments at once: each instruction sequence
 is lowered to flat integer tensors (issue cycles, port-mask ids, latencies,
 occupancies, dependency producers), the wave is padded to
 ``(n_experiments, n_uops)``, and the dispatch/dependency recurrence runs as
-a vectorized kernel.  Three backends share the lowering and packing layers:
+a vectorized kernel.  Two backends share the lowering and packing layers:
 
 * ``numpy`` — the baseline: a Python loop over μop *positions* with one
   vectorized step across all experiment lanes (Python overhead is
@@ -16,16 +16,10 @@ a vectorized kernel.  Three backends share the lowering and packing layers:
   ``mask_table`` LUT kept resident on device and chunk dispatch pipelined
   against host packing (double-buffered: pack chunk k+1 while chunk k
   executes).
-* ``pallas`` — the same recurrence as a ``pl.pallas_call`` kernel: the grid
-  runs over blocks of experiment lanes, a ``fori_loop`` walks μop positions
-  with the per-lane state (``done`` history, port-free times, port counts)
-  carried in on-chip values.  It runs in interpret mode off-TPU only — the
-  correctness twin of the recurrence, not a speed path: Mosaic refuses its
-  per-step gathers (``dynamic_slice``) for the TPU, so on a TPU the
-  backend is refused at construction.
 
-With no backend given, :class:`~repro.core.simulator.SimMachine` picks the
-kernel from the platform (:func:`default_backend`): ``jax`` on a TPU,
+With no backend given, :func:`open_machine` (behind
+:class:`~repro.core.simulator.SimMachine`) takes ``REPRO_SIM_BACKEND``,
+else the platform's pick (:func:`default_backend`): ``jax`` on a TPU,
 ``numpy`` elsewhere.
 
 Wave execution is amortized end-to-end:
@@ -66,6 +60,7 @@ NumPy calls.
 """
 from __future__ import annotations
 
+import os
 import threading
 import warnings
 
@@ -87,7 +82,7 @@ _W_TMP, _W_MEM, _W_CELL = 0, 1, 2
 # recipe kinds
 _K_NORMAL, _K_ZERO_NOUOP, _K_ELIM = 0, 1, 2
 
-BACKENDS = ("numpy", "jax", "pallas")
+BACKENDS = ("numpy", "jax")
 
 # thin-chunk scalar-oracle crossover (lanes): below this many parallel
 # lanes the array program's fixed per-step dispatch cost exceeds the
@@ -107,16 +102,6 @@ DEFAULT_MIN_LANES = 4
 # came from, plus at most the waves of entries refreshed by later hits.
 DEFAULT_LOWER_CACHE = 4096
 
-# lane-block width for the pallas kernel grid (the TPU lane dimension)
-_PALLAS_LANE_BLOCK = 128
-
-# why the pallas backend is refused on a TPU (pinned by
-# tests/test_tpu_compile.py, which compiles it for a described chip)
-PALLAS_TPU_REFUSAL = ("the pallas wave kernel does not compile for the TPU: "
-                      "Mosaic has no lowering for its per-step gathers "
-                      "(Unimplemented primitive in Pallas TPU lowering: "
-                      "dynamic_slice); use backend='jax'")
-
 
 class BackendUnavailable(RuntimeError):
     """A device backend was requested but jax is not importable."""
@@ -127,6 +112,30 @@ def default_backend() -> str:
     TPU, the ``numpy`` kernel everywhere else."""
     from repro.core.device_mesh import device_platform  # noqa: PLC0415
     return "jax" if device_platform() == "tpu" else "numpy"
+
+
+def open_machine(uarch: UArch, isa: ISA, backend: str | None = None,
+                 table_index: UopTableIndex | None = None,
+                 min_lanes: int | None = None,
+                 devices=None) -> BatchSimMachine:
+    """Build the batched machine on ``backend``, else the
+    ``REPRO_SIM_BACKEND`` environment variable, else the platform's pick
+    (:func:`default_backend`).  A device backend whose jax is not
+    importable falls back to ``numpy`` with a warning; an unknown name
+    raises ``ValueError``."""
+    backend = (backend or os.environ.get("REPRO_SIM_BACKEND")
+               or default_backend())
+    if min_lanes is None:
+        min_lanes = DEFAULT_MIN_LANES
+    try:
+        return BatchSimMachine(uarch, isa, backend=backend,
+                               table_index=table_index, min_lanes=min_lanes,
+                               devices=devices)
+    except BackendUnavailable:
+        warnings.warn(f"sim backend {backend!r} unavailable (jax not "
+                      "importable); falling back to numpy", stacklevel=4)
+        return BatchSimMachine(uarch, isa, backend="numpy",
+                               table_index=table_index, min_lanes=min_lanes)
 
 
 def _fault_key(code) -> str:
@@ -395,7 +404,7 @@ class BatchSimMachine:
     Same observable contract as :class:`~repro.core.simulator.SimMachine`
     (cycles + per-port μop counts, including harness overhead), plus
     :meth:`run_batch` — and bit-identical results to the scalar oracle on
-    every backend (``numpy``, ``jax``, ``pallas``)."""
+    both backends (``numpy``, ``jax``)."""
 
     counters_available = True
 
@@ -409,17 +418,12 @@ class BatchSimMachine:
         if backend != "numpy" and _jax() is None:
             raise BackendUnavailable(f"{backend} backend requested but jax "
                                      "is not importable")
-        if backend == "pallas":
-            from repro.core.device_mesh import (  # noqa: PLC0415
-                device_platform)
-            if device_platform() == "tpu":
-                raise NotImplementedError(PALLAS_TPU_REFUSAL)
         self.uarch = uarch
         self.isa = isa
         self.name = uarch.name
         self.ports = uarch.ports
         self.backend = backend
-        # device placement spec for the jax/pallas backends: None (the
+        # device placement spec for the jax backend: None (the
         # REPRO_SIM_DEVICES env knob, default all available devices), an
         # integer count, "all", or an explicit jax device sequence —
         # resolved lazily by core/device_mesh when the device executor is
@@ -441,13 +445,12 @@ class BatchSimMachine:
         self._lower_max = lower_cache_entries
         self.lowering_stats = {"hits": 0, "misses": 0, "evictions": 0}
         # backend degradation counters: "<from>-><to>" -> chunks rerouted
-        # down the backend chain (pallas -> jax -> numpy -> scalar oracle)
+        # down the backend chain (jax -> numpy -> scalar oracle)
         # after a kernel-path failure.  Results stay bit-identical (every
         # backend computes the same integers); the engine snapshots these
         # through degraded_stats() into EngineStats.degraded_chunks.
         self.degraded: dict = {}
-        self._device = None             # lazy _DeviceExec (jax/pallas)
-        self._device_fb: dict = {}      # degraded-backend executors
+        self._device = None             # lazy _DeviceExec (jax)
         # guards the machine's shared mutable host state (lowering-cache
         # LRU, recipe memo, lazy device/scalar init) across concurrent
         # run_batch callers; slot leasing has its own lock in _DeviceExec
@@ -466,7 +469,6 @@ class BatchSimMachine:
         with self._host_lock:
             self.devices = devices
             self._device = None
-            self._device_fb.clear()
 
     def device_stats(self) -> dict:
         """Device-kernel telemetry: compile count (the CI recompile probe
@@ -569,8 +571,7 @@ class BatchSimMachine:
                                        span="wave.degraded")
         else:
             try:
-                self._run_device(batched, codes, progs, out, kernel_lock,
-                                 self.backend)
+                self._run_device(batched, codes, progs, out, kernel_lock)
             except Exception as exc:
                 self._degrade_device(batched, codes, progs, out,
                                      kernel_lock, exc)
@@ -624,29 +625,16 @@ class BatchSimMachine:
                         exc: BaseException) -> None:
         """Backend degradation chain: on a device-path failure, re-run
         every chunk that never produced results on the next backend down
-        (pallas -> jax -> numpy -> scalar oracle).  Results stay
-        bit-identical — each backend computes the same integers — so a
-        degraded wave is correct, just slower; reroutes are counted per
-        transition (``degraded_stats()``).  A wave that fails even the
-        scalar oracle re-raises, handing the measurement engine's
-        bisecting retry the job of isolating the poison experiment."""
-        prev = self.backend
-        for nxt in (("jax",) if self.backend == "pallas" else ()):
-            remaining = [c for c in batched
-                         if any(out[i] is None for i in c)]
-            if not remaining:
-                return
-            self._note_degraded(prev, nxt, len(remaining), exc)
-            try:
-                self._run_device(remaining, codes, progs, out,
-                                 kernel_lock, nxt)
-                return
-            except Exception as e2:
-                exc, prev = e2, nxt
+        (jax -> numpy -> scalar oracle).  Results stay bit-identical —
+        each backend computes the same integers — so a degraded wave is
+        correct, just slower; reroutes are counted per transition
+        (``degraded_stats()``).  A wave that fails even the scalar oracle
+        re-raises, handing the measurement engine's bisecting retry the
+        job of isolating the poison experiment."""
         remaining = [c for c in batched if any(out[i] is None for i in c)]
         if not remaining:
             return
-        self._note_degraded(prev, "numpy", len(remaining), exc)
+        self._note_degraded(self.backend, "numpy", len(remaining), exc)
         for c in remaining:
             try:
                 self._chunk_numpy(c, codes, progs, out, kernel_lock)
@@ -1181,31 +1169,19 @@ class BatchSimMachine:
             pc_key[rows, best] += vinc[j]
         return done, (pc_key >> idx_bits).astype(np.int32)
 
-    # -- device backends (jax scan / pallas) ---------------------------
-    def _device_exec(self, kind: str):
-        """Lazy per-backend device executor.  The machine's configured
-        backend keeps the historical ``_device`` slot (``set_devices``
-        drops it for rebuild); degraded-backend executors are cached
-        separately and share the same resolved device placement."""
+    # -- device backend (jax scan) -------------------------------------
+    def _device_exec(self):
+        """The lazy device executor (``set_devices`` drops it for
+        rebuild on the new placement)."""
         from repro.core.device_mesh import resolve_devices  # noqa: PLC0415
         with self._host_lock:
-            if kind == self.backend:
-                if self._device is None:
-                    self._device = _DeviceExec(
-                        self._comp, kind,
-                        devices=resolve_devices(self.devices),
-                        min_lanes=self.min_lanes)
-                return self._device
-            dev = self._device_fb.get(kind)
-            if dev is None:
-                dev = self._device_fb[kind] = _DeviceExec(
-                    self._comp, kind,
-                    devices=resolve_devices(self.devices),
+            if self._device is None:
+                self._device = _DeviceExec(
+                    self._comp, devices=resolve_devices(self.devices),
                     min_lanes=self.min_lanes)
-            return dev
+            return self._device
 
-    def _run_device(self, batched, codes, progs, out, kernel_lock,
-                    kind: str) -> None:
+    def _run_device(self, batched, codes, progs, out, kernel_lock) -> None:
         """Pipelined, lane-sharded device execution: each chunk is split
         into per-core lane shards whose kernels run concurrently on the
         device pool (the kernels release the GIL), and chunk k+1 is packed
@@ -1217,7 +1193,7 @@ class BatchSimMachine:
         ``kernel_lock`` is held only around kernel dispatch, never around
         host packing or result waits."""
         from collections import deque  # noqa: PLC0415
-        dev = self._device_exec(kind)
+        dev = self._device_exec()
         traced = obs.enabled()
         pending: deque = deque()
         jobs: list = []
@@ -1229,12 +1205,12 @@ class BatchSimMachine:
                 if faults.active():
                     faults.check_wave("wave.kernel",
                                       [_fault_key(codes[i]) for i in c],
-                                      backend=kind)
+                                      backend=self.backend)
                 jobs = []
                 steps = slots = rows = 0
                 with obs.span("wave.pack", lanes=len(c)) as psp:
                     if faults.active():
-                        faults.check("wave.pack", backend=kind)
+                        faults.check("wave.pack", backend=self.backend)
                     for sc in dev.shard(c, progs):
                         S0 = max(progs[i].n_rows for i in sc)
                         if S0 == 0:    # a shard of all-zero-μop programs
@@ -1320,13 +1296,11 @@ class _DeviceExec:
     _BUCKETS_MAX = 8     # bucket slot-ring pool bound (LRU)
     _SHARD_MIN_LANES = 64
 
-    def __init__(self, comp: CompiledUArch, kind: str, devices=(),
+    def __init__(self, comp: CompiledUArch, devices=(),
                  min_lanes: int = DEFAULT_MIN_LANES):
-        import os  # noqa: PLC0415
         from repro.core.device_mesh import (  # noqa: PLC0415
             dispatch_lock, jax_devices)
         self.comp = comp
-        self.kind = kind
         self.devices = tuple(devices)
         self.min_lanes = max(min_lanes, 1)
         all_devs = jax_devices()
@@ -1361,7 +1335,7 @@ class _DeviceExec:
         self._rings: dict = {}   # bucket -> slot list (LRU by bucket)
 
     def stats(self) -> dict:
-        return {"backend": self.kind, "compiles": self.compiles,
+        return {"backend": "jax", "compiles": self.compiles,
                 "kernel_calls": self.kernel_calls,
                 "buckets": sorted(self.buckets),
                 "mesh": self.mesh_mode,
@@ -1505,7 +1479,7 @@ class _DeviceExec:
         kernels release the GIL), and machines placed on disjoint device
         subsets must never serialize each other's kernels."""
         if faults.active():
-            faults.check("device.dispatch", backend=self.kind)
+            faults.check("device.dispatch", backend="jax")
         pool = self._get_pool()
         M, P = self.comp.mask_table.shape
         calls = []
@@ -1518,16 +1492,15 @@ class _DeviceExec:
                 n_use = slot.n_use
                 e_dev = E // n_use
                 mesh = lane_mesh(self.devices[:n_use])
-                fn, compiled_now = _compiled_kernel(
-                    self.kind, S, e_dev, R, M, P, mesh=mesh)
+                fn, compiled_now = _compiled_kernel(S, e_dev, R, M, P,
+                                                    mesh=mesh)
                 lut = self._mesh_lut(n_use)
                 devs = mesh.devices
                 self._record(devs, (S, e_dev, R), compiled_now,
                              pk.E, e_dev)
             else:
                 n_use, e_dev = 1, E
-                fn, compiled_now = _compiled_kernel(self.kind, S, E, R,
-                                                    M, P)
+                fn, compiled_now = _compiled_kernel(S, E, R, M, P)
                 lut = self.lut
                 devs = self.devices[:1]
                 self._record(devs, (S, E, R), compiled_now, pk.E, E)
@@ -1610,8 +1583,7 @@ _EXEC_CACHE_MAX = 128
 _EXEC_LOCK = threading.Lock()
 
 
-def _compiled_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
-                     mesh=None):
+def _compiled_kernel(S: int, E: int, R: int, M: int, P: int, mesh=None):
     """AOT-compiled dispatch kernel for one shape bucket.  Returns
     ``(callable, compiled_now)``; the executable cache is module-wide, so
     machines sharing bucket shapes share compilations — and a module lock
@@ -1624,7 +1596,7 @@ def _compiled_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
     takes ``(E * mesh.n, S)``-shaped operands whose lane blocks land one
     per device.  Executables are device-bound, so the mesh's device-id
     tuple is part of the cache key."""
-    key = (kind, S, E, R, M, P) + ((mesh.key,) if mesh is not None else ())
+    key = (S, E, R, M, P) + ((mesh.key,) if mesh is not None else ())
     hit = _EXEC_CACHE.get(key)
     if hit is not None:
         return hit, False
@@ -1635,12 +1607,12 @@ def _compiled_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
         from repro.core.device_mesh import (  # noqa: PLC0415
             enable_compile_cache)
         enable_compile_cache()
-        with obs.span("wave.compile", backend=kind, bucket=list(key[1:6])):
-            return _compile_kernel(kind, key, mesh), True
+        with obs.span("wave.compile", backend="jax", bucket=list(key[:5])):
+            return _compile_kernel(key, mesh), True
 
 
-def lower_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
-                 mesh=None, sharding=None):
+def lower_kernel(S: int, E: int, R: int, M: int, P: int, mesh=None,
+                 sharding=None):
     """Lower the dispatch kernel for one shape bucket (``jax.stages.
     Lowered``; ``.compile()`` it).  Without ``mesh`` the operands are
     placed by ``sharding`` (default: jax's default device) — pass a
@@ -1656,9 +1628,7 @@ def lower_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
         device = next(iter(sharding.device_set))
     else:
         device = jax.devices()[0]
-    fn = (_build_pallas_fn(S, E, R, M, P,
-                           interpret=device.platform != "tpu")
-          if kind == "pallas" else _build_scan_fn())
+    fn = _build_scan_fn()
     sd = jax.ShapeDtypeStruct
     if mesh is None:
         s2 = s3 = rep = sharding
@@ -1688,8 +1658,8 @@ def lower_kernel(kind: str, S: int, E: int, R: int, M: int, P: int,
     return jax.jit(fn, donate_argnums=donate).lower(*shapes)
 
 
-def _compile_kernel(kind, key, mesh=None):
-    compiled = lower_kernel(kind, *key[1:6], mesh=mesh).compile()
+def _compile_kernel(key, mesh=None):
+    compiled = lower_kernel(*key[:5], mesh=mesh).compile()
     while len(_EXEC_CACHE) >= _EXEC_CACHE_MAX:
         _EXEC_CACHE.pop(next(iter(_EXEC_CACHE)))
     _EXEC_CACHE[key] = compiled
@@ -1811,88 +1781,3 @@ def _build_scan_fn():
 
     return run
 
-
-def _build_pallas_fn(S: int, E: int, R: int, M: int, P: int,
-                     interpret: bool):
-    """The dispatch recurrence as a ``pl.pallas_call`` kernel: grid over
-    blocks of experiment lanes, ``fori_loop`` over μop positions, per-lane
-    state (``done`` history, port-free times, port counts) carried in
-    on-chip values; the tie-break is the same two-pass min as the scan
-    kernel.  Off-TPU it runs in ``interpret`` mode; compiled for a TPU,
-    Mosaic refuses it (see :data:`PALLAS_TPU_REFUSAL`)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    B = _PALLAS_LANE_BLOCK
-    while E % B:
-        B //= 2
-    grid = (E // B,)
-    big = 2**31 - 1   # disallowed-port sentinel (see the scan kernel)
-
-    def kernel(issue_ref, mask_ref, lat_ref, blk_ref, valid_ref, prod_ref,
-               delta_ref, lut_ref, done_ref, counts_ref):
-        lut = lut_ref[:]
-        issue = issue_ref[:]               # (B, S) — one block of lanes
-        mask_id = mask_ref[:]
-        lat = lat_ref[:]
-        blk = blk_ref[:]
-        valid = valid_ref[:]
-        prod = prod_ref[:]                 # (B, S, R)
-        delta = delta_ref[:]
-
-        def step(j, carry):
-            done, pf, pc = carry           # (B,S), (B,P), (B,P)
-            pr = jax.lax.dynamic_index_in_dim(prod, j, 1, False)
-            de = jax.lax.dynamic_index_in_dim(delta, j, 1, False)
-            val = jnp.where(
-                pr >= 0,
-                jnp.take_along_axis(done, jnp.maximum(pr, 0), axis=1),
-                0) + de
-            isu = jax.lax.dynamic_index_in_dim(issue, j, 1, False)
-            ready = jnp.maximum(isu, val.max(axis=1))
-            mid = jax.lax.dynamic_index_in_dim(mask_id, j, 1, False)
-            allowed = lut[mid]
-            t = jnp.maximum(ready[:, None], pf)
-            ta = jnp.where(allowed, t, big)
-            tmin = ta.min(axis=1)
-            cnt = jnp.where(ta == tmin[:, None], pc, big)
-            cmin = cnt.min(axis=1)
-            best = jnp.argmax(cnt == cmin[:, None], axis=1)
-            va = jax.lax.dynamic_index_in_dim(valid, j, 1, False)
-            la = jax.lax.dynamic_index_in_dim(lat, j, 1, False)
-            bl = jax.lax.dynamic_index_in_dim(blk, j, 1, False)
-            done = jax.lax.dynamic_update_index_in_dim(
-                done, jnp.where(va, tmin + la, 0), j, 1)
-            hit = (jnp.arange(P)[None, :] == best[:, None]) & va[:, None]
-            pf = jnp.where(hit, (tmin + bl)[:, None], pf)
-            pc = pc + hit.astype(jnp.int32)
-            return done, pf, pc
-
-        done0 = jnp.zeros((B, S), jnp.int32)
-        pf0 = jnp.zeros((B, P), jnp.int32)
-        pc0 = jnp.zeros((B, P), jnp.int32)
-        done, _, pc = jax.lax.fori_loop(0, S, step, (done0, pf0, pc0))
-        done_ref[:] = done
-        counts_ref[:] = pc
-
-    lane2 = pl.BlockSpec((B, S), lambda i: (i, 0))
-    call = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[lane2, lane2, lane2, lane2, lane2,
-                  pl.BlockSpec((B, S, R), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((B, S, R), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((M, P), lambda i: (0, 0))],
-        out_specs=[lane2,
-                   pl.BlockSpec((B, P), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((E, S), jnp.int32),
-                   jax.ShapeDtypeStruct((E, P), jnp.int32)],
-        interpret=interpret,
-    )
-
-    def run(issue, mask_id, lat, blk, valid, prod, delta, lut):
-        return tuple(call(issue, mask_id, lat, blk, valid, prod, delta,
-                          lut))
-
-    return run

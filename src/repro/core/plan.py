@@ -170,8 +170,8 @@ class WaveScheduler:
     already saturates the interpreter, so two campaign workers' kernels
     interleaving under the GIL just thrash each other (measured ~8x CPU
     inflation).  Host lowering and wave packing always run outside the
-    lock, and plan stepping stays concurrent throughout.  Device backends
-    (jax/pallas) do not take this lock at all: their compiled kernels
+    lock, and plan stepping stays concurrent throughout.  The device
+    backend (jax) does not take this lock at all: its compiled kernels
     release the GIL, and dispatch serializes on the backend's own
     *per-device-subset* lock (:func:`repro.core.device_mesh
     .dispatch_lock`) instead — machines placed on disjoint device subsets

@@ -76,14 +76,14 @@ def _implicit_reg(opname: str, otype: str) -> str:
 class SimMachine:
     """The measurable black box.
 
-    ``backend`` selects the batched wave-execution kernel (``numpy``,
-    ``jax``, or ``pallas``; default: the ``REPRO_SIM_BACKEND`` environment
-    variable, else the platform's pick — ``jax`` on a TPU, ``numpy``
-    elsewhere) — results are bit-identical on every backend.
+    ``backend`` names the batched wave-execution kernel; ``None`` leaves
+    the choice to :func:`repro.core.batch_sim.open_machine` (see there
+    for the names and the order it resolves them in) — results are
+    bit-identical on every backend.
     ``min_lanes`` is the thin-chunk scalar-oracle crossover forwarded to
     :class:`~repro.core.batch_sim.BatchSimMachine` (default: the measured
     crossover, see ``bench_batch_sim``).  ``devices``
-    selects the device placement for the jax/pallas backends (an integer
+    selects the device placement for the device backend (an integer
     count, ``"all"``, or an explicit jax device sequence; default: the
     ``REPRO_SIM_DEVICES`` environment variable, else all available) —
     more than one device shards wave lanes across a 1-D mesh, still
@@ -132,9 +132,10 @@ class SimMachine:
         return self._batch.device_stats() if self._batch else {}
 
     def degraded_stats(self) -> dict:
-        """Per-transition backend degradation counters (e.g. ``"numpy->
-        scalar"``); surfaced through ``engine_stats`` so campaigns over
-        lazy machines report degradations the same way direct
+        """Per-transition backend degradation counters (see
+        :meth:`~repro.core.batch_sim.BatchSimMachine.degraded_stats`);
+        surfaced through ``engine_stats`` so campaigns over lazy machines
+        report degradations the same way direct
         :class:`~repro.core.batch_sim.BatchSimMachine` campaigns do."""
         return self._batch.degraded_stats() if self._batch else {}
 
@@ -172,27 +173,11 @@ class SimMachine:
         if self._batch is None:
             try:
                 from repro.core.batch_sim import (  # noqa: PLC0415
-                    DEFAULT_MIN_LANES, BackendUnavailable, BatchSimMachine,
-                    default_backend)
-                import os  # noqa: PLC0415
-                backend = (self.backend
-                           or os.environ.get("REPRO_SIM_BACKEND")
-                           or default_backend())
-                min_lanes = (DEFAULT_MIN_LANES if self.min_lanes is None
-                             else self.min_lanes)
-                try:
-                    self._batch = BatchSimMachine(
-                        self.uarch, self.isa, backend=backend,
-                        table_index=self._table_index, min_lanes=min_lanes,
-                        devices=self.devices)
-                except BackendUnavailable:   # jax backend, jax missing
-                    import warnings  # noqa: PLC0415
-                    warnings.warn(f"sim backend {backend!r} unavailable "
-                                  "(jax not importable); falling back to "
-                                  "numpy", stacklevel=3)
-                    self._batch = BatchSimMachine(
-                        self.uarch, self.isa, backend="numpy",
-                        table_index=self._table_index, min_lanes=min_lanes)
+                    open_machine)
+                self._batch = open_machine(
+                    self.uarch, self.isa, backend=self.backend,
+                    table_index=self._table_index, min_lanes=self.min_lanes,
+                    devices=self.devices)
             except ImportError:   # no numpy: scalar fallback
                 self._batch = False
         return self._batch

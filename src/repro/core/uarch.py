@@ -13,7 +13,7 @@ Two kinds live here:
    undocumented zero idiom (§7.3.6).
 
 2. **TPU v5e hardware constants** for the roofline analysis, plus the
-   TPU-unit port model used by the Pallas kernel characterization
+   TPU-unit port model used by the on-chip kernel characterization
    (`kernels/microbench.py` blocking kernels).
 """
 from __future__ import annotations

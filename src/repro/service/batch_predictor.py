@@ -125,7 +125,7 @@ class BatchPredictor:
         wave of blocks at once (Algorithm-2 differencing on the attached
         machine; the engine dedups the wave and executes the miss-set
         through the machine's batched backend — device-resident when the
-        machine's backend is ``jax``/``pallas``, with warm waves skipping
+        machine's backend is ``jax``, with warm waves skipping
         lowering via the machine's lowering cache).  ``kernel_lock``
         serializes GIL-bound kernel execution against other engines
         sharing the lock; host lowering/packing stays concurrent.

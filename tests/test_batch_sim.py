@@ -66,10 +66,14 @@ def _interesting_wave(isa):
     return codes
 
 
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 @pytest.mark.parametrize("uarch", sorted(SIM_UARCHES))
-def test_batch_identical_to_scalar_on_sim_uarches(uarch):
+def test_batch_identical_to_scalar_on_sim_uarches(uarch, backend):
+    if backend != "numpy":
+        pytest.importorskip("jax")
     ua = SIM_UARCHES[uarch]
-    assert_wave_matches(ua, TEST_ISA, _interesting_wave(TEST_ISA))
+    assert_wave_matches(ua, TEST_ISA, _interesting_wave(TEST_ISA),
+                        backend=backend)
 
 
 def test_batch_identical_on_tpu_unit_model():
@@ -126,12 +130,15 @@ def _generated_wave(seed, n_codes=8):
     return ua, isa, codes
 
 
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 @pytest.mark.parametrize("seed", range(6))
-def test_batch_identical_on_generated_ground_truths(seed):
+def test_batch_identical_on_generated_ground_truths(seed, backend):
     """Generated ISAs bring memory operands, vector registers, dividers,
     zero idioms and eliminable moves into the wave."""
+    if backend != "numpy":
+        pytest.importorskip("jax")
     ua, isa, codes = _generated_wave(seed)
-    assert_wave_matches(ua, isa, codes)
+    assert_wave_matches(ua, isa, codes, backend=backend)
 
 
 if HAVE_HYPOTHESIS:
@@ -171,7 +178,7 @@ def test_ragged_wave_lengths():
     assert_wave_matches(SIM_SKL, TEST_ISA, codes)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax"])
 def test_device_backends_match_when_available(backend):
     pytest.importorskip("jax")
     body = independent_seq(TEST_ISA["ADD_R64_R64"], RegPool(), 3)
@@ -181,7 +188,7 @@ def test_device_backends_match_when_available(backend):
     assert_wave_matches(SIM_SKL, TEST_ISA, codes, backend=backend)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax"])
 def test_device_backends_on_interesting_wave(backend):
     pytest.importorskip("jax")
     assert_wave_matches(SIM_SKL, TEST_ISA, _interesting_wave(TEST_ISA),
@@ -201,33 +208,19 @@ def test_platform_picks_wave_backend(monkeypatch, platform, want):
         .backend == "numpy"
 
 
-def test_pallas_backend_refused_on_tpu(monkeypatch):
-    """On a TPU the pallas backend fails at construction with Mosaic's
-    reason, through SimMachine too — never interpret mode on the chip and
-    never a silent fallback to another kernel."""
-    pytest.importorskip("jax")
-    from repro.core import batch_sim, device_mesh
-    monkeypatch.setattr(device_mesh, "device_platform", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="dynamic_slice"):
-        BatchSimMachine(SIM_SKL, TEST_ISA, backend="pallas")
-    with pytest.raises(NotImplementedError,
-                       match=batch_sim.PALLAS_TPU_REFUSAL[:40]):
-        SimMachine(SIM_SKL, TEST_ISA, backend="pallas").batch_machine()
-    monkeypatch.setattr(device_mesh, "device_platform", lambda: "cpu")
-    assert BatchSimMachine(SIM_SKL, TEST_ISA, backend="pallas").backend \
-        == "pallas"
-
-
 def test_unknown_instruction_raises_keyerror_like_scalar():
     b = BatchSimMachine(SIM_SKL, TEST_ISA)
     with pytest.raises(KeyError):
         b.run_batch([[Instr("NO_SUCH_INSTR", {})] * 4])
 
 
-def test_wide_port_machine_counts_exact():
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_wide_port_machine_counts_exact(backend):
     """A uarch with more than 16 ports: the kernel's packed dispatch key
     must keep port counts and tie-breaks exact (regression: the port
     axis once shared bit space with the μop counts)."""
+    if backend != "numpy":
+        pytest.importorskip("jax")
     from repro.core.isa import GPR, ISA, InstrSpec, op
     ports = tuple(f"p{i:02d}" for i in range(18))
     b = {"WADD": beh(uop(frozenset(ports), ("op2",), ("op1",)))}
@@ -236,7 +229,7 @@ def test_wide_port_machine_counts_exact():
                          (op("op1", GPR, "w"), op("op2", GPR, "r")))])
     codes = [[Instr("WADD", {"op1": f"R{i}", "op2": f"R{i + 20}"})
               for i in range(20)] * reps for reps in (1, 5, 11)]
-    assert_wave_matches(ua, isa, codes)
+    assert_wave_matches(ua, isa, codes, backend=backend)
 
 
 def test_body_period_detection():
@@ -277,10 +270,10 @@ def _tie_wave(ports, isa_ports=None):
     return ua, isa, codes
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_tie_break_equivalence_at_port_count_boundaries(backend):
     """The numpy kernel breaks dispatch ties with one packed
-    (time, count, port) argmin key; the device kernels use a two-pass min.
+    (time, count, port) argmin key; the device kernel uses a two-pass min.
     On waves where several ports tie on both time and count, every backend
     must pick the same port as the scalar oracle — checked on the widest
     SIM_UARCHES port set and on an 18-port machine (so the port axis
@@ -292,20 +285,6 @@ def test_tie_break_equivalence_at_port_count_boundaries(backend):
     assert_wave_matches(ua, isa, codes, backend=backend)
     ua18, isa18, codes18 = _tie_wave([f"p{i:02d}" for i in range(18)])
     assert_wave_matches(ua18, isa18, codes18, backend=backend)
-
-
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_backends_agree_on_wide_port_machine(backend):
-    pytest.importorskip("jax")
-    from repro.core.isa import GPR, ISA, InstrSpec, op
-    ports = tuple(f"p{i:02d}" for i in range(18))
-    b = {"WADD": beh(uop(frozenset(ports), ("op2",), ("op1",)))}
-    ua = UArch("sim_wide", ports, 8, b, overhead_cycles=0)
-    isa = ISA([InstrSpec("WADD", "WADD",
-                         (op("op1", GPR, "w"), op("op2", GPR, "r")))])
-    codes = [[Instr("WADD", {"op1": f"R{i}", "op2": f"R{i + 20}"})
-              for i in range(20)] * reps for reps in (1, 5, 11)]
-    assert_wave_matches(ua, isa, codes, backend=backend)
 
 
 # ---------------------------------------------------------------------------
@@ -851,7 +830,7 @@ def test_engine_submits_waves_through_run_batch():
     assert rec.waves == [6]   # 3 unique experiments x (n_small, n_large)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax"])
 def test_sharded_chunk_with_uniform_lengths(backend):
     """Regression: two lane shards of one chunk map to the SAME shape
     bucket when every sequence has the same length — the shards must not
@@ -867,7 +846,7 @@ def test_sharded_chunk_with_uniform_lengths(backend):
     assert_wave_matches(SIM_SKL, TEST_ISA, codes, backend=backend)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax"])
 def test_all_zero_uop_shard(backend):
     """Regression: a lane shard whose programs all lower to zero μops
     (zero-idiom bodies) must fill in overhead-only Counters instead of
@@ -885,7 +864,7 @@ def test_all_zero_uop_shard(backend):
     assert_wave_matches(SIM_SKL, TEST_ISA, adds + zeros, backend=backend)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax"])
 def test_buffer_reuse_with_narrower_read_width(backend):
     """Regression: a reused device buffer whose previous occupant had a
     wider per-μop read width (max_r) must not leak stale producer columns
@@ -927,7 +906,7 @@ def test_device_slot_leased_until_extraction():
     from repro.core.batch_sim import _DeviceExec
 
     m = BatchSimMachine(SIM_SKL, TEST_ISA, backend="jax", min_lanes=1)
-    dev = _DeviceExec(m._comp, "jax")
+    dev = _DeviceExec(m._comp)
     s1 = dev.acquire(8, 8, 1)
     # with no kernel in flight at all (the state a resolved future used
     # to leave behind), a leased slot must never be handed out again
@@ -988,7 +967,7 @@ def test_simmachine_degenerate_wave_respects_min_lanes():
 
 
 def test_characterize_xml_identical_across_backends():
-    """End-to-end: a characterization driven through the device backends
+    """End-to-end: a characterization driven through the device backend
     exports byte-identical model XML to the numpy backend (the whole
     pipeline — scheduler fusion, engine cache, lowering cache, bucketed
     kernels, pipelined dispatch — preserves every measured number)."""
@@ -1003,13 +982,11 @@ def test_characterize_xml_identical_across_backends():
         MeasurementEngine(SimMachine(SIM_SKL, TEST_ISA, backend="numpy")),
         TEST_ISA, names)
     ref_xml = model_io.to_xml(ref, TEST_ISA)
-    for backend in ("jax", "pallas"):
-        m = SimMachine(SIM_SKL, TEST_ISA, backend=backend)
-        model = characterize(MeasurementEngine(m), TEST_ISA, names)
-        assert model_io.to_xml(model, TEST_ISA) == ref_xml, backend
-        assert m.lowering_stats["misses"] > 0
-        assert m.device_stats()["compiles"] <= \
-            len(m.device_stats()["buckets"])
+    m = SimMachine(SIM_SKL, TEST_ISA, backend="jax")
+    model = characterize(MeasurementEngine(m), TEST_ISA, names)
+    assert model_io.to_xml(model, TEST_ISA) == ref_xml
+    assert m.lowering_stats["misses"] > 0
+    assert m.device_stats()["compiles"] <= len(m.device_stats()["buckets"])
 
 
 def test_campaign_runs_on_device_backend(monkeypatch):
@@ -1068,7 +1045,7 @@ def test_dispatch_span_counts_match_packed_shards(monkeypatch):
     pytest.importorskip("jax")
     from repro.core.batch_sim import _DeviceExec
     m = BatchSimMachine(SIM_SKL, TEST_ISA, backend="jax", min_lanes=1)
-    m._device_exec("jax").n_workers = 3      # several shards per chunk
+    m._device_exec().n_workers = 3      # several shards per chunk
     seen = []
     real = _DeviceExec.dispatch
 
@@ -1123,7 +1100,7 @@ def test_device_kernel_module_names():
     from repro.core.batch_sim import lower_kernel
     from repro.service.batch_predictor import lower_port_bound
     M, P = compile_uarch(SIM_SKL, TEST_ISA).mask_table.shape
-    wave = lower_kernel("jax", 32, 8, 2, M, P).compile().as_text()
+    wave = lower_kernel(32, 8, 2, M, P).compile().as_text()
     bound = lower_port_bound(8, 3, 4).compile().as_text()
     assert wave.startswith("HloModule jit_run,")
     assert bound.startswith("HloModule jit_port_bound_kernel,")

@@ -265,6 +265,29 @@ def test_dispatch_fault_degrades_device_to_numpy():
     assert m.degraded_stats().get("jax->numpy", 0) >= 1
 
 
+@pytest.mark.skipif(not HAVE_JAX, reason="jax backend unavailable")
+def test_device_and_numpy_faults_land_on_scalar_oracle():
+    """The whole chain on a jax machine: a failed dispatch reroutes to
+    numpy, a failed numpy kernel to the scalar oracle, and the wave's
+    results are still bit-identical, each transition counted once per
+    chunk it rerouted."""
+    fplan.set_plan(FaultPlan.from_spec(
+        "device.dispatch:raise;wave.kernel:raise:backend=numpy"))
+    m = _machine(backend="jax")
+    codes = [list(e.code) for e in _experiments(poison=False)]
+    with pytest.warns(UserWarning) as rec:
+        got = m.run_batch(codes)
+    said = " ".join(str(w.message) for w in rec)
+    assert "degraded jax->numpy" in said and "degraded numpy->scalar" in said
+    scalar = SimMachine(SIM_UARCHES["sim_skl"], TEST_ISA)
+    for g, code in zip(got, codes):
+        ref = scalar.run(list(code))
+        assert g.cycles == ref.cycles and g.port_uops == ref.port_uops
+    st = m.degraded_stats()
+    assert set(st) == {"jax->numpy", "numpy->scalar"}
+    assert st["jax->numpy"] == st["numpy->scalar"] >= 1
+
+
 def test_campaign_completes_with_quarantine():
     fplan.set_plan(FaultPlan.from_spec(
         f"wave.kernel:raise:match={POISON}"))

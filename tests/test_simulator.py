@@ -1,6 +1,7 @@
 """Simulated-machine behaviors the paper's algorithms must contend with."""
 import pytest
 
+from repro.core.batch_sim import BACKENDS, BatchSimMachine
 from repro.core.isa import TEST_ISA
 from repro.core.machine import RegPool, independent_seq, measure
 from repro.core.simulator import Instr, SimMachine
@@ -116,3 +117,16 @@ def test_partial_register_stall(m):
     ])
     assert stalled.cycles == pytest.approx(
         clean.cycles + UA.partial_stall_penalty, abs=0.1)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: BatchSimMachine(SIM_SKL, TEST_ISA, backend="pallas"),
+    lambda: SimMachine(SIM_SKL, TEST_ISA, backend="pallas").batch_machine(),
+], ids=["batch_sim", "simmachine"])
+def test_removed_pallas_backend_is_unknown(build):
+    """The wave kernels are numpy and the jax scan; any other name fails
+    construction on every platform, through ``SimMachine`` too — never a
+    silent fallback to another kernel."""
+    assert BACKENDS == ("numpy", "jax")
+    with pytest.raises(ValueError, match="unknown backend 'pallas'"):
+        build()

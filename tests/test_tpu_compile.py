@@ -61,7 +61,7 @@ def _device_bytes(compiled) -> int:
 
 @pytest.mark.parametrize("S,E", [(1536, 256), (256, 2048)])
 def test_scan_kernel_compiles_for_v5e(one_chip, lut_shape, S, E):
-    compiled = batch_sim.lower_kernel("jax", S, E, R, *lut_shape,
+    compiled = batch_sim.lower_kernel(S, E, R, *lut_shape,
                                       sharding=one_chip).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
@@ -69,8 +69,7 @@ def test_scan_kernel_compiles_for_v5e(one_chip, lut_shape, S, E):
 def test_scan_kernel_compiles_on_four_chip_mesh(topo, lut_shape):
     mesh = LaneMesh(topo.devices)
     assert mesh.n == 4
-    lowered = batch_sim.lower_kernel("jax", 1536, 256, R, *lut_shape,
-                                     mesh=mesh)
+    lowered = batch_sim.lower_kernel(1536, 256, R, *lut_shape, mesh=mesh)
     compiled = lowered.compile()
     # lanes are independent: the partitioned program needs no collective
     hlo = compiled.as_text()
@@ -88,12 +87,3 @@ def test_predictor_kernel_compiles_for_v5e(one_chip):
                                 sharding=one_chip).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
-
-def test_pallas_kernel_is_refused_for_v5e(one_chip, lut_shape):
-    """Mosaic cannot lower the pallas kernel's per-step gathers; a kernel
-    rewrite that compiles must update this test and the construction-time
-    refusal (``batch_sim.PALLAS_TPU_REFUSAL``)."""
-    with pytest.raises(NotImplementedError, match="dynamic_slice"):
-        batch_sim.lower_kernel("pallas", 64, 128, R, *lut_shape,
-                               sharding=one_chip).compile()
-    assert "dynamic_slice" in batch_sim.PALLAS_TPU_REFUSAL
